@@ -607,6 +607,9 @@ def _main(quick: bool = False, json_path: str | Path = BENCH_JSON,
 if __name__ == "__main__":
     import argparse
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--backend", default=None,

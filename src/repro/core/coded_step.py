@@ -30,6 +30,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import Precision
 
 from repro.core import density_evolution
 from repro.core.encoding import (Moments, encode_moment,
@@ -148,9 +149,11 @@ class Scheme2:
     def gradient(self, theta: jax.Array, straggler_mask: jax.Array):
         """Return (approx gradient, |U_t|)."""
         if self.seeded_encode:
-            z = self._encode(self.C @ theta)  # gather(M θ)
+            z = self._encode(jnp.matmul(self.C, theta,
+                                        precision=Precision.HIGHEST))
         else:
-            z = self.C @ theta  # (N,) worker inner products (codeword of C)
+            # (N,) worker inner products (codeword of C)
+            z = jnp.matmul(self.C, theta, precision=Precision.HIGHEST)
         erased = self.worker_mask_to_erasure(straggler_mask)
         c_hat, unresolved = self.engine.recover(z, erased)
         return self.finish_gradient(c_hat, unresolved)
@@ -168,9 +171,11 @@ class Scheme2:
         whole batch for the worst-case ``decode_iters`` budget.
         """
         if self.seeded_encode:
-            Z = self._encode((theta_B @ self.C.T).T).T  # (B, N)
+            Z = self._encode(jnp.matmul(
+                theta_B, self.C.T, precision=Precision.HIGHEST).T).T  # (B, N)
         else:
-            Z = theta_B @ self.C.T  # (B, N)
+            Z = jnp.matmul(theta_B, self.C.T,
+                           precision=Precision.HIGHEST)  # (B, N)
         erased_B = jax.vmap(self.worker_mask_to_erasure)(straggler_mask_B)
         c_hat, unresolved = self.engine.recover_batch(Z, erased_B)
         return self.finish_gradient(c_hat, unresolved)
@@ -208,7 +213,8 @@ class Scheme1:
     def gradient(self, theta: jax.Array, straggler_mask: jax.Array):
         G = jnp.asarray(self.code.G, theta.dtype)  # (N, K)
         # Worker j computes one inner product per block: Z[i, j] = <C[i, j], theta>.
-        Z = jnp.einsum("bnk,k->bn", self.C_blocks, theta)  # (k/K, N)
+        Z = jnp.einsum("bnk,k->bn", self.C_blocks, theta,
+                       precision=Precision.HIGHEST)  # (k/K, N)
         avail = (~straggler_mask).astype(theta.dtype)
         # Weighted least squares that zeroes out straggler rows:
         Gw = G * avail[:, None]
@@ -265,7 +271,8 @@ class Scheme2Blocked:
     def gradient(self, theta: jax.Array, straggler_mask: jax.Array):
         eng = self.engine
         nb = self.C_blocks.shape[0]
-        Z = jnp.einsum("bnk,k->nb", self.C_blocks, theta)  # (N, k/K)
+        Z = jnp.einsum("bnk,k->nb", self.C_blocks, theta,
+                       precision=Precision.HIGHEST)  # (N, k/K)
         dec = eng.decode(eng.erase(Z, straggler_mask), straggler_mask)
         g, unresolved_flat = blocked_epilogue(dec.values, dec.erased, self.b,
                                               K=self.code.K, nb=nb)

@@ -86,8 +86,10 @@ backend    what runs
            * "auto" — crossover rule from :mod:`repro.core.hwcaps`:
              "gather" iff the dense round's modeled FLOPs exceed
              ``mxu_advantage ×`` the gather round's (advantage 1.0 on
-             CPU/interpret — gather always wins; 8.0 placeholder on TPU
-             until ROADMAP item 5's profiling replaces it).
+             CPU/interpret — gather always wins); on TPU always
+             "dense_tile", because the gather round does not compile
+             there (Mosaic rejects its in-kernel gathers: a compiled
+             "gather" launch raises ``NotImplementedError``).
 "replay"   straight-line numeric REPLAY of a pattern-compiled
            :class:`PeelSchedule` — no round loop, no convergence test, no
            solvability counting: the elimination order is a pure function
@@ -158,6 +160,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import Precision
 import numpy as np
 
 from repro.core.ldpc import (
@@ -186,6 +189,8 @@ __all__ = [
     "resolve_backend",
     "vmem_bytes_estimate",
     "pick_tile_bp",
+    "peel_error_bound",
+    "F32_OP_ERROR",
     "SEEDED_MODES",
 ]
 
@@ -216,18 +221,19 @@ def _kernel_shape(code) -> tuple[int, int]:
 
 
 def vmem_bytes_estimate(code, dtype=jnp.float32, batch: int = 1, *,
-                        bv: int = 128) -> int:
+                        bv: int = 8) -> int:
     """Estimated per-grid-step VMEM working set of the RESIDENT fused kernel.
 
     ``code`` may be an :class:`LDPCCode`, an ``(H, Hb)`` tuple, or a raw
     ``(p, N)`` shape pair.  The resident kernel keeps several ``(p, N)``
     buffers live per round (H itself plus its boolean mask, the column/row
-    iotas, and the resolution one-hot) alongside the ``(N, bv)`` payload
-    carry and the ``(N, 1)`` masks; the estimate counts them at the
-    kernel's f32 compute width (``dtype`` below f32 still computes in f32).
-    The batch axis shares H and streams one slot's payload per grid step,
-    so ``batch`` does not scale the per-step set — the argument is accepted
-    (and validated) so call sites can pass their batch size symmetrically.
+    iotas, and the resolution one-hot) alongside the lane-major ``(bv, N)``
+    payload carry (``bv`` payload rows per grid step) and the ``(1, N)``
+    masks; the estimate counts them at the kernel's f32 compute width
+    (``dtype`` below f32 still computes in f32).  The batch axis shares H
+    and streams one slot's payload per grid step, so ``batch`` does not
+    scale the per-step set — the argument is accepted (and validated) so
+    call sites can pass their batch size symmetrically.
 
     ``backend="auto"`` compares this against ``vmem_budget_bytes`` to pick
     resident-"pallas" vs "pallas_tiled"; benchmarks use it to fail over
@@ -347,10 +353,11 @@ def peel_round(
     """
     N = values.shape[0]
     e = erased.astype(H.dtype)  # (N,)
-    cnt = Hb.astype(H.dtype) @ e  # (p,) number of erased neighbours per check
+    # (p,) number of erased neighbours per check
+    cnt = jnp.matmul(Hb.astype(H.dtype), e, precision=Precision.HIGHEST)
     solvable = cnt == 1.0  # (p,)
     known = values * (1.0 - e)[:, None]  # zero out erased entries
-    row_sums = H @ known  # (p, V)
+    row_sums = jnp.matmul(H, known, precision=Precision.HIGHEST)  # (p, V)
     # The (unique) erased neighbour of each row; arbitrary for non-solvable rows.
     pos = jnp.argmax(Hb & erased[None, :], axis=1)  # (p,)
     coeff = jnp.take_along_axis(H, pos[:, None], axis=1)[:, 0]  # (p,)
@@ -615,6 +622,71 @@ def compile_peel_schedule(code: LDPCCode, erased) -> PeelSchedule:
     return sched
 
 
+# Unit roundoff the value contract charges per f32 operation: IEEE f32
+# rounds to 2^-24; TPU matmuls at Precision.HIGHEST emulate f32 with
+# several bf16 passes and stay within a few ulps of it.  2^-22 (4 ulps)
+# covers both.
+F32_OP_ERROR = 2.0 ** -22
+
+
+def peel_error_bound(code: LDPCCode, erased, values, rounds: int | None = None,
+                     *, input_error=None) -> np.ndarray:
+    """Per-coordinate forward error bound of a peeled decode — THE value
+    contract every backend is held to.
+
+    Every backend follows the same erasure trajectory exactly; what may
+    differ is f32 rounding, and peeling AMPLIFIES it along the chain: a
+    check resolving ``c_t = -(Σ_s w_s c_s) / a`` turns its sources' errors
+    ``ε_s`` into ``(Σ_s |w_s| (ε_s + γ|c_s|)) / |a| + u|c_t|`` with
+    ``γ = (r_max + 2)·u`` for the products, the ``r_max``-term sum and the
+    divide (``u`` = :data:`F32_OP_ERROR`), so a chain of small ``|a|``
+    multiplies the error of its first link.  This walks the pattern's
+    :func:`compile_peel_schedule` order on the host in float64 and returns
+    that bound for every coordinate: ``input_error`` (default: one f32
+    rounding of ``|values|``) on received symbols, the propagated bound on
+    coordinates resolved within ``rounds`` (default: all), ``inf`` on
+    those left erased.  Where duplicate checks resolve one coordinate, the
+    larger of the lowest- and highest-row bounds is taken, covering both
+    tie-break rules the backends use.
+
+    ``values`` are the DECODED values ``(N,)`` or ``(N, V)`` (their
+    magnitudes stand in for the exact ones); a decode is within contract
+    when ``|decoded - exact| <= bound`` on every resolved coordinate.
+    """
+    sched = compile_peel_schedule(code, erased)
+    mag = np.abs(np.asarray(values, np.float64))
+    squeeze = mag.ndim == 1
+    if squeeze:
+        mag = mag[:, None]
+    N, V = sched.N, mag.shape[1]
+    u = F32_OP_ERROR
+    gamma = (sched.r_max + 2) * u
+    err = np.zeros((N + 1, V))               # row N: the sentinel column
+    err[:N] = (u * mag if input_error is None
+               else np.broadcast_to(np.abs(np.asarray(
+                   input_error, np.float64)).reshape(N, -1), (N, V)))
+    err[:N][np.asarray(erased, bool)] = np.inf
+    mag = np.concatenate([mag, np.zeros((1, V))])
+    n_rounds = (sched.n_rounds if rounds is None
+                else min(int(rounds), sched.n_rounds))
+    for k in range(n_rounds):
+        s0, s1 = int(sched.offsets[k]), int(sched.offsets[k + 1])
+        tgt = sched.target[s0:s1]
+        bound = np.zeros((s1 - s0, V))
+        for rule in ("lo", "hi"):
+            idx = getattr(sched, f"idx_{rule}")[s0:s1]
+            w = np.abs(getattr(sched, f"w_{rule}")[s0:s1])[:, :, None]
+            a = np.abs(getattr(sched, f"coeff_{rule}")[s0:s1])[:, None]
+            # w == 0 on the target slot (still erased: err = inf) and on
+            # padding; mask so 0 * inf cannot poison the sum
+            e_src = np.where(w > 0, err[idx], 0.0)
+            src = w * (e_src + gamma * mag[idx])
+            bound = np.maximum(bound, src.sum(axis=1) / a + u * mag[tgt])
+        err[tgt] = bound
+    out = err[:N]
+    return out[:, 0] if squeeze else out
+
+
 def _check_schedule(sched: PeelSchedule, code, erased) -> None:
     if not isinstance(sched, PeelSchedule):
         raise ValueError(f"schedule must be a PeelSchedule; got "
@@ -811,6 +883,10 @@ def _resolve_seeded_mode(seeded_mode: str, code, V: int, bp: int) -> str:
         raise ValueError(f"unknown seeded_mode {seeded_mode!r}; "
                          f"want one of {SEEDED_MODES}")
     if seeded_mode == "auto":
+        if jax.default_backend() == "tpu":
+            # the gather round does not compile for TPU (kernel.py
+            # interpret_only), whatever the FLOPs model says
+            return "dense_tile"
         from repro.core.hwcaps import pick_seeded_mode
 
         return pick_seeded_mode(_seeded_spec(code), V, bp=bp)
@@ -829,6 +905,7 @@ def peel_decode(
     vmem_budget_bytes: int | None = None,
     seeded_mode: str = "dense_tile",
     schedule: PeelSchedule | None = None,
+    H: jax.Array | None = None,
 ) -> DecodeResult:
     """Run exactly ``iters`` flooding rounds (the paper's fixed-D decode).
 
@@ -864,13 +941,13 @@ def peel_decode(
     elif backend == "pallas":
         from repro.kernels.ldpc_peel import peel_decode_pallas
 
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e = peel_decode_pallas(H, v, e, iters)
     elif backend == "pallas_tiled":
         from repro.kernels.ldpc_peel import peel_decode_tiled_pallas
 
         bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e = peel_decode_tiled_pallas(H, v, e, iters, bp=bp_, bv=bv_)
     elif backend == "pallas_seeded":
         from repro.kernels.ldpc_peel import peel_decode_seeded_pallas
@@ -880,7 +957,7 @@ def peel_decode(
         v, e = peel_decode_seeded_pallas(_seeded_spec(code), v, e, iters,
                                          bp=bp_, bv=bv_, mode=mode)
     else:
-        H, Hb = _mats(code, v.dtype)
+        H, Hb = _mats(code, v.dtype, H)
         v, e = peel_fixed_dense(H, Hb, v, e, iters)
     if squeeze:
         v = v[:, 0]
@@ -992,6 +1069,7 @@ def peel_decode_batch(
     vmem_budget_bytes: int | None = None,
     seeded_mode: str = "dense_tile",
     schedules=None,
+    H: jax.Array | None = None,
 ) -> DecodeResult:
     """Decode ``B`` INDEPENDENT erasure patterns in one launch.
 
@@ -1040,13 +1118,13 @@ def peel_decode_batch(
     elif backend == "pallas":
         from repro.kernels.ldpc_peel import peel_decode_batch_pallas
 
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e = peel_decode_batch_pallas(H, v, e, iters)
     elif backend == "pallas_tiled":
         from repro.kernels.ldpc_peel import peel_decode_batch_tiled_pallas
 
         bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e = peel_decode_batch_tiled_pallas(H, v, e, iters, bp=bp_, bv=bv_)
     elif backend == "pallas_seeded":
         from repro.kernels.ldpc_peel import peel_decode_batch_seeded_pallas
@@ -1057,7 +1135,7 @@ def peel_decode_batch(
                                                iters, bp=bp_, bv=bv_,
                                                mode=mode)
     else:
-        H, Hb = _mats(code, v.dtype)
+        H, Hb = _mats(code, v.dtype, H)
         v, e = _peel_fixed_dense_batch(H, Hb, v, e, iters)
     if squeeze:
         v = v[:, :, 0]
@@ -1113,6 +1191,7 @@ def peel_decode_adaptive(
     vmem_budget_bytes: int | None = None,
     seeded_mode: str = "dense_tile",
     schedule: PeelSchedule | None = None,
+    H: jax.Array | None = None,
 ) -> DecodeResult:
     """Decode until fixpoint (no check resolves) or ``max_iters`` rounds.
 
@@ -1150,13 +1229,13 @@ def peel_decode_adaptive(
     elif backend == "pallas":
         from repro.kernels.ldpc_peel import peel_decode_adaptive_pallas
 
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e, d = peel_decode_adaptive_pallas(H, v, e, int(max_iters))
     elif backend == "pallas_tiled":
         from repro.kernels.ldpc_peel import peel_decode_adaptive_tiled_pallas
 
         bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e, d = peel_decode_adaptive_tiled_pallas(H, v, e, int(max_iters),
                                                     bp=bp_, bv=bv_)
     elif backend == "pallas_seeded":
@@ -1168,7 +1247,7 @@ def peel_decode_adaptive(
             _seeded_spec(code), v, e, int(max_iters), bp=bp_, bv=bv_,
             mode=mode)
     else:
-        H, Hb = _mats(code, v.dtype)
+        H, Hb = _mats(code, v.dtype, H)
         v, e, d = _peel_adaptive(H, Hb, v, e, int(max_iters))
     if squeeze:
         v = v[:, 0]
@@ -1270,6 +1349,7 @@ def peel_decode_batch_adaptive(
     vmem_budget_bytes: int | None = None,
     seeded_mode: str = "dense_tile",
     schedules=None,
+    H: jax.Array | None = None,
 ) -> DecodeResult:
     """Decode ``B`` independent patterns with PER-SLOT early exit, one launch.
 
@@ -1330,14 +1410,14 @@ def peel_decode_batch_adaptive(
     elif backend == "pallas":
         from repro.kernels.ldpc_peel import peel_decode_batch_adaptive_pallas
 
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e, d = peel_decode_batch_adaptive_pallas(H, v, e, budgets)
     elif backend == "pallas_tiled":
         from repro.kernels.ldpc_peel import (
             peel_decode_batch_adaptive_tiled_pallas)
 
         bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
-        H = jnp.asarray(code.H, _float_dtype(v.dtype))
+        H = _dense_h(code, H, v.dtype)
         v, e, d = peel_decode_batch_adaptive_tiled_pallas(H, v, e, budgets,
                                                           bp=bp_, bv=bv_)
     elif backend == "pallas_seeded":
@@ -1349,7 +1429,7 @@ def peel_decode_batch_adaptive(
         v, e, d = peel_decode_batch_adaptive_seeded_pallas(
             _seeded_spec(code), v, e, budgets, bp=bp_, bv=bv_, mode=mode)
     else:
-        H, Hb = _mats(code, v.dtype)
+        H, Hb = _mats(code, v.dtype, H)
         v, e, d = _peel_adaptive_dense_batch(H, Hb, v, e, budgets)
     if squeeze:
         v = v[:, :, 0]
@@ -1371,7 +1451,19 @@ def _float_dtype(dtype):
     return dtype if jnp.issubdtype(dtype, jnp.floating) else jnp.float32
 
 
-def _mats(code, dtype) -> tuple[jax.Array, jax.Array]:
+def _dense_h(code, H, dtype) -> jax.Array:
+    """The materialized parity-check matrix for the dense / resident /
+    tiled backends: the caller's runtime operand ``H`` when given, else
+    ``code.H``.  A jitted caller passes ``H`` in so the ``(p, N)`` matrix
+    is an argument of its program rather than a constant embedded in it
+    (512 MiB at N = 16384)."""
+    return jnp.asarray(code.H if H is None else H, _float_dtype(dtype))
+
+
+def _mats(code, dtype, H=None) -> tuple[jax.Array, jax.Array]:
+    if H is not None:
+        H = _dense_h(code, H, dtype)
+        return H, H != 0.0
     if isinstance(code, LDPCCode):
         H = jnp.asarray(code.H, dtype=_float_dtype(dtype))
         Hb = jnp.asarray(code.H_mask)

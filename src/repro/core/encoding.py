@@ -40,6 +40,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import Precision
 
 from repro.core.ldpc import (LDPCCode, seeded_generator_rows,
                              seeded_structure)
@@ -59,7 +60,8 @@ def second_moment(X: jax.Array, y: jax.Array) -> Moments:
     """M = X^T X, b = X^T y — the one-time preprocessing pass."""
     X = jnp.asarray(X)
     y = jnp.asarray(y)
-    return Moments(X.T @ X, X.T @ y)
+    return Moments(jnp.matmul(X.T, X, precision=Precision.HIGHEST),
+                   jnp.matmul(X.T, y, precision=Precision.HIGHEST))
 
 
 def encode_moment(code: LDPCCode, M: jax.Array) -> jax.Array:
@@ -69,7 +71,7 @@ def encode_moment(code: LDPCCode, M: jax.Array) -> jax.Array:
         raise ValueError(f"code dimension K={code.K} != k={M.shape[0]}; "
                          "use encode_moment_blocks for K | k")
     G = jnp.asarray(code.G, M.dtype)
-    return G @ M
+    return jnp.matmul(G, M, precision=Precision.HIGHEST)
 
 
 def encode_moment_blocks(code: LDPCCode, M: jax.Array) -> jax.Array:
@@ -85,7 +87,7 @@ def encode_moment_blocks(code: LDPCCode, M: jax.Array) -> jax.Array:
     nb = k // code.K
     G = jnp.asarray(code.G, M.dtype)
     blocks = M.reshape(nb, code.K, k)
-    return jnp.einsum("nk,bkj->bnj", G, blocks)
+    return jnp.einsum("nk,bkj->bnj", G, blocks, precision=Precision.HIGHEST)
 
 
 def generator_gather_tables(code: LDPCCode) -> tuple[jax.Array, jax.Array]:

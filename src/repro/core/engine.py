@@ -50,6 +50,7 @@ import logging
 
 import jax
 import jax.numpy as jnp
+from jax.lax import Precision
 import numpy as np
 
 from repro.core.decoder import (
@@ -126,6 +127,13 @@ class CodedComputeEngine:
     # "pallas_seeded" round sub-dispatch: dense_tile | gather | auto
     # (the hwcaps FLOPs-crossover rule); ignored by other backends.
     seeded_mode: str = "dense_tile"
+    # The materialized parity-check matrix as a RUNTIME operand for the
+    # dense / pallas / pallas_tiled backends (None = code.H).  Jitted
+    # callers hand it in (``dataclasses.replace(engine, H=H)`` inside the
+    # traced function) so the (p, N) matrix is an argument of their
+    # program, not a constant compiled into it.
+    H: jax.Array | None = dataclasses.field(default=None, compare=False,
+                                            repr=False)
 
     def __post_init__(self) -> None:
         # Fail fast on unknown/unsupported backend names (same matrix as
@@ -177,7 +185,7 @@ class CodedComputeEngine:
     def _tile_kw(self) -> dict:
         return {"bp": self.bp, "bv": self.bv,
                 "vmem_budget_bytes": self.vmem_budget_bytes,
-                "seeded_mode": self.seeded_mode}
+                "seeded_mode": self.seeded_mode, "H": self.H}
 
     def _schedule_kw(self, erased, *, batch: bool) -> dict:
         """``schedule=``/``schedules=`` operands for replay dispatch, from
@@ -227,7 +235,7 @@ class CodedComputeEngine:
     def encode(self, payload: jax.Array) -> jax.Array:
         """(K, ...) systematic payload → (N, ...) worker symbols (G @ m)."""
         G = jnp.asarray(self.code.G, payload.dtype)
-        return G @ payload
+        return jnp.matmul(G, payload, precision=Precision.HIGHEST)
 
     @staticmethod
     def erase(symbols: jax.Array, mask: jax.Array) -> jax.Array:

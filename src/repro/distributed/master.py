@@ -53,13 +53,14 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import Precision
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.coded_step import Scheme2
-from repro.core.decoder import DecodeResult
+from repro.core.decoder import DecodeResult, resolve_backend
 from repro.core.engine import blocked_epilogue
 from repro.core.straggler import DelayModel
 from repro.distributed.sharded_decode import (
@@ -93,6 +94,23 @@ __all__ = ["DistributedRunResult", "DistributedCodedGD",
 BUDGET_MODES = ("fixed", "telemetry")
 MASTER_DECODES = ("single", "sharded", "replay")
 WORKER_ENCODES = ("materialized", "seeded", "seeded-fused")
+
+
+def master_decode_operand(engine, device):
+    """The ``(p, N)`` f32 parity-check matrix the engine's decode reads,
+    placed on ``device`` once — or None when the resolved backend reads
+    none (sparse tables, seeded tiles and replay schedules are small).
+
+    Master programs take it as an ARGUMENT and decode through
+    ``dataclasses.replace(engine, H=H)``: closed over, the matrix would be
+    compiled into every program as a constant (512 MiB at N = 16384).
+    """
+    backend = resolve_backend(engine.backend, engine.code,
+                              adaptive=engine.adaptive,
+                              vmem_budget_bytes=engine.vmem_budget_bytes)
+    if backend not in ("dense", "pallas", "pallas_tiled"):
+        return None
+    return jax.device_put(jnp.asarray(engine.code.H, jnp.float32), device)
 
 
 def _record_step_metrics(driver: str, *, rounds: int, unresolved: int,
@@ -273,6 +291,9 @@ class DistributedCodedGD:
             self._C_sharded = shard_encoded_rows(
                 jnp.asarray(self.scheme.C), self.mesh, self.topology)
         self.master_device = self.mesh.devices.flat[0]
+        self._decode_H = (master_decode_operand(self.scheme.engine,
+                                                self.master_device)
+                          if self.master_decode == "single" else None)
         if self.master_decode == "sharded":
             # Check tiles partitioned over the workers axis, once at build.
             self._sharded_tables = shard_check_tables(self.scheme.code,
@@ -396,7 +417,8 @@ class DistributedCodedGD:
                 theta2 = scheme.projection(theta - scheme.lr * g)
                 return theta2, n_unres
 
-            def master_program(z, worker_mask, theta, budget):
+            def master_program(z, worker_mask, theta, budget, H):
+                del H             # replay reads its schedule, never H
                 erased = topo.to_symbol_erasure(worker_mask)
                 z = r_eng.erase(z, erased)    # idempotent, mirrors recover()
                 if fixed_mode:
@@ -420,10 +442,11 @@ class DistributedCodedGD:
         # on the already-zeroed survivors is idempotent, so the decode sees
         # exactly what Scheme2.gradient feeds it.
         if self.budget_mode == "fixed":
-            def master_program(z, worker_mask, theta, budget):
+            def master_program(z, worker_mask, theta, budget, H):
                 del budget  # fixed-D decode; kept for a stable signature
                 erased = topo.to_symbol_erasure(worker_mask)
-                c_hat, unresolved = eng.recover(z, erased)
+                c_hat, unresolved = dataclasses.replace(eng, H=H).recover(
+                    z, erased)
                 g, n_unres = scheme.finish_gradient(c_hat, unresolved)
                 theta2 = scheme.projection(theta - scheme.lr * g)
                 return theta2, n_unres, jnp.int32(eng.decode_iters)
@@ -431,10 +454,10 @@ class DistributedCodedGD:
             # Telemetry mode rides the engine's batched-adaptive decode at
             # B=1: the round budget is a TRACED (1,) operand (changing
             # budgets never recompile) and rounds_used surfaces per step.
-            def master_program(z, worker_mask, theta, budget):
+            def master_program(z, worker_mask, theta, budget, H):
                 erased = topo.to_symbol_erasure(worker_mask)
-                dec = eng.decode_batch(z[None], erased[None], adaptive=True,
-                                       budgets=budget)
+                dec = dataclasses.replace(eng, H=H).decode_batch(
+                    z[None], erased[None], adaptive=True, budgets=budget)
                 c_hat, unresolved = eng.systematic(dec)
                 g, n_unres = scheme.finish_gradient(c_hat[0], unresolved[0])
                 theta2 = scheme.projection(theta - scheme.lr * g)
@@ -501,7 +524,7 @@ class DistributedCodedGD:
                 # jit places alongside them.
                 theta2, n_unres, rounds = self._master_program(
                     self._mshard(z), self._mshard(mask_rep),
-                    self._mshard(theta_rep), budget_arr)
+                    self._mshard(theta_rep), budget_arr, self._decode_H)
             n_unres, rounds = int(n_unres), int(rounds)
         _record_step_metrics("sync", rounds=rounds, unresolved=n_unres,
                              budget=budget)
@@ -599,12 +622,14 @@ class DistributedCodedAggregator:
     ``G @ partials`` — a 2-D-payload :func:`repro.distributed.worker
     .build_worker_products` launch (each systematic symbol is a flattened
     ``(dim,)`` partial gradient) — then the master peels the survivor
-    symbols and sums the recovered shards.  Row-block matmuls are bitwise
-    identical to the full ``G @ partials`` and the decode runs as a
-    single-device program on the master, so ``aggregate`` is BIT-IDENTICAL
-    to the single-device :meth:`CodedAggregator.aggregate` under the lifted
-    mask (asserted by ``repro.distributed.selfcheck --grad-agg`` on the
-    fake 8-device mesh).
+    symbols and sums the recovered shards.  The decode runs as a
+    single-device program on the master, so the erasure trajectory (and
+    unresolved count) matches the single-device
+    :meth:`CodedAggregator.aggregate` under the lifted mask exactly; the
+    sums agree within the decoder's value contract, since a row-block GEMM
+    may block its f32 sums differently from the full ``G @ partials``
+    (asserted by ``repro.distributed.selfcheck --grad-agg`` on the fake
+    8-device mesh).
     """
 
     agg: "CodedAggregator"
@@ -637,15 +662,17 @@ class DistributedCodedAggregator:
             erased = topo.to_symbol_erasure(worker_mask)
             return worker_products(G_sh, partials, erased)
 
-        def master_program(z, worker_mask):
+        def master_program(z, worker_mask, H):
             erased = topo.to_symbol_erasure(worker_mask)
-            recovered, unresolved = eng.recover(z, erased)
+            recovered, unresolved = dataclasses.replace(eng, H=H).recover(
+                z, erased)
             total = recovered.sum(axis=0) * agg.debias_scale
             return total, unresolved.sum()
 
         self._worker_program = jax.jit(worker_program,
                                        out_shardings=self._replicated)
         self._master_program = jax.jit(master_program)
+        self._decode_H = master_decode_operand(eng, self.master_device)
 
     @property
     def n_workers(self) -> int:
@@ -669,7 +696,8 @@ class DistributedCodedAggregator:
             jax.device_put(worker_mask, self._replicated))
         m = self.master_device
         total, n_unres = self._master_program(
-            jax.device_put(z, m), jax.device_put(worker_mask, m))
+            jax.device_put(z, m), jax.device_put(worker_mask, m),
+            self._decode_H)
         return total, int(n_unres)
 
 
@@ -708,7 +736,8 @@ def build_distributed_gd_step(k: int, K: int, decode_iters: int, dtype,
         # C_shard (nb, N/W, k/data); theta_shard (k/data,) — partial sums
         # over the feature axis, one psum over "data" completes the dot.
         z = jnp.einsum("bnk,k->nb", C_shard,
-                       theta_shard.astype(C_shard.dtype))
+                       theta_shard.astype(C_shard.dtype),
+                       precision=Precision.HIGHEST)
         z = jax.lax.psum(z.astype(jnp.float32), "data")
         return jnp.where(erased_shard[:, None], 0.0, z)
 
@@ -720,6 +749,13 @@ def build_distributed_gd_step(k: int, K: int, decode_iters: int, dtype,
     def epilogue(vals, erased_sym, theta, b, lr):
         g, _ = blocked_epilogue(vals, erased_sym, b, K=K, nb=nb)
         return theta - lr * g
+
+    def to_master(*xs):
+        # The master decode consumes the survivors and its tables in the
+        # MASTER layout (replicated: every chip runs the same unpartitioned
+        # decode), resharded explicitly — under an Explicit-axes mesh the
+        # row-sharded worker output cannot feed the decode's scatters.
+        return tuple(jax.sharding.reshard(x, sh()) for x in xs)
 
     common = (
         jax.ShapeDtypeStruct((k,), jnp.float32),   # theta
@@ -735,6 +771,7 @@ def build_distributed_gd_step(k: int, K: int, decode_iters: int, dtype,
         def step_dense(C_blocks, H, theta, b, worker_mask, lr):
             erased = topo.to_symbol_erasure(worker_mask)
             z = worker_products(C_blocks, theta, erased)
+            H, z, erased = to_master(H, z, erased)
             vals, er = peel_fixed_dense(H, H != 0.0, z, erased, decode_iters)
             return epilogue(vals, er, theta, b, lr)
 
@@ -750,6 +787,7 @@ def build_distributed_gd_step(k: int, K: int, decode_iters: int, dtype,
     def step_sparse(C_blocks, H_idx, H_val, theta, b, worker_mask, lr):
         erased = topo.to_symbol_erasure(worker_mask)
         z = worker_products(C_blocks, theta, erased)
+        H_idx, H_val, z, erased = to_master(H_idx, H_val, z, erased)
         vals, er = peel_fixed_sparse(H_idx, H_val, z, erased, decode_iters)
         return epilogue(vals, er, theta, b, lr)
 

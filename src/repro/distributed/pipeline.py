@@ -315,7 +315,8 @@ class AsyncDistributedCodedGD:
             epilogue = jax.jit(replay_epilogue, donate_argnums=(3,))
 
             def replay_master(z, worker_mask, theta, tbar, fold_dg, t,
-                              budget, theta_star):
+                              budget, theta_star, H):
+                del H             # replay reads its schedule, never H
                 erased = topo.to_symbol_erasure(jnp.asarray(worker_mask))
                 z = r_eng.erase(z, erased)
                 if fixed:
@@ -335,14 +336,15 @@ class AsyncDistributedCodedGD:
             return replay_master
 
         def master_program(z, worker_mask, theta, tbar, fold_dg, t, budget,
-                           theta_star):
+                           theta_star, H):
+            eng_h = dataclasses.replace(eng, H=H)
             erased = topo.to_symbol_erasure(worker_mask)
             if fixed:
-                c_hat, unresolved = eng.recover(z, erased)
+                c_hat, unresolved = eng_h.recover(z, erased)
                 rounds = jnp.int32(eng.decode_iters)
             else:
-                dec = eng.decode_batch(z[None], erased[None], adaptive=True,
-                                       budgets=budget)
+                dec = eng_h.decode_batch(z[None], erased[None],
+                                         adaptive=True, budgets=budget)
                 c_hat, unresolved = eng.systematic(dec)
                 c_hat, unresolved = c_hat[0], unresolved[0]
                 rounds = dec.rounds_used[0]
@@ -377,10 +379,11 @@ class AsyncDistributedCodedGD:
         scheme, topo = self.scheme, self.topology
         eng = scheme.engine
 
-        def fold_program(z, remaining_mask, u_old, budget, w):
+        def fold_program(z, remaining_mask, u_old, budget, w, H):
             erased = topo.to_symbol_erasure(remaining_mask)
-            dec = eng.decode_batch(eng.erase(z, erased)[None], erased[None],
-                                   adaptive=True, budgets=budget)
+            dec = dataclasses.replace(eng, H=H).decode_batch(
+                eng.erase(z, erased)[None], erased[None], adaptive=True,
+                budgets=budget)
             c2, u2 = eng.systematic(dec)
             c2, u2 = c2[0], u2[0]
             newly = u_old & ~u2
@@ -561,7 +564,7 @@ class AsyncDistributedCodedGD:
                                    source_step=entry.step, lag=lag):
                             delta, u2, n_new, fr = self._fold_program(
                                 entry.z_m, remaining, entry.u, fold_budget,
-                                w_tau)
+                                w_tau, sync._decode_H)
                         entry.u = u2
                         fold_newly.setdefault(entry.step, []).append(n_new)
                         fold_rounds_at.setdefault(t, []).append(fr)
@@ -590,7 +593,8 @@ class AsyncDistributedCodedGD:
                 theta_m, tbar_m, nu, r, err, u_mask = master(
                     sync._mshard(z), np.asarray(c.cut), theta_m, tbar_m,
                     fold_dg, np.float32(t),
-                    np.asarray([c.budget], np.int32), tstar_m)
+                    np.asarray([c.budget], np.int32), tstar_m,
+                    sync._decode_H)
 
             # 4. broadcast the new iterate (zero-copy on the master device:
             # the replicated put reuses θ's buffer for the master shard)

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.decoder import peel_round_sparse
 from repro.core.ldpc import LDPCCode
@@ -132,4 +132,4 @@ def build_sharded_decode(mesh: Mesh, *, iters: int, adaptive: bool = False,
         local_decode, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False)
+        check_vma=False)

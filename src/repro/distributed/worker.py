@@ -44,7 +44,8 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.lax import Precision
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.encoding import gather_encode, generator_structure_of
@@ -100,7 +101,7 @@ def local_products(C_shard: jax.Array, theta: jax.Array,
     aggregation) — ``z`` is then ``(rows, dim)`` with the erasure mask
     broadcast over the payload axis.
     """
-    z = C_shard @ theta
+    z = jnp.matmul(C_shard, theta, precision=Precision.HIGHEST)
     m = erased_shard
     while m.ndim < z.ndim:
         m = m[..., None]
@@ -136,7 +137,7 @@ def local_products_seeded(idx_shard: jax.Array, coeff_shard: jax.Array,
     :func:`repro.core.encoding.gather_encode` runs on a single device, so
     products are bit-identical to ``Scheme2.build_seeded``'s.
     """
-    y = M @ theta
+    y = jnp.matmul(M, theta, precision=Precision.HIGHEST)
     z = gather_encode(idx_shard, coeff_shard, y)
     m = erased_shard
     while m.ndim < z.ndim:
@@ -181,7 +182,7 @@ def build_seeded_fused_worker_products(code: LDPCCode, mesh: Mesh):
     rows_per = code.N // n_workers
 
     def local_fused(M, theta, erased_shard):
-        y = M @ theta
+        y = jnp.matmul(M, theta, precision=Precision.HIGHEST)
         row0 = jax.lax.axis_index("workers") * rows_per
         z = encode_seeded_fused_pallas(st, y, row0, n_out=rows_per)
         m = erased_shard
@@ -189,12 +190,12 @@ def build_seeded_fused_worker_products(code: LDPCCode, mesh: Mesh):
             m = m[..., None]
         return jnp.where(m, 0.0, z)
 
-    # check_rep=False: shard_map has no replication rule for pallas_call;
+    # check_vma=False: shard_map cannot infer pallas_call's varying axes;
     # the kernel only READS the replicated y, so the spec stays sound.
     return shard_map(
         local_fused, mesh=mesh,
         in_specs=(P(), P(), P("workers")),
-        out_specs=P("workers"), check_rep=False)
+        out_specs=P("workers"), check_vma=False)
 
 
 def shard_generator_tables(code: LDPCCode, mesh: Mesh,

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import detect_interpret
+
 __all__ = ["matmul_kernel_call"]
 
 
@@ -34,7 +36,8 @@ def _mm_kernel(a_ref, b_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def matmul_kernel_call(A: jax.Array, B: jax.Array, *, bm: int = 128,
-                       bn: int = 128, bk: int = 128, interpret: bool = True):
+                       bn: int = 128, bk: int = 128,
+                       interpret: bool | None = None):
     """A (M, K) @ B (K, N) -> (M, N) f32. Dims must be tile multiples
     (ops.py pads)."""
     M, K = A.shape
@@ -50,5 +53,5 @@ def matmul_kernel_call(A: jax.Array, B: jax.Array, *, bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        interpret=interpret,
+        interpret=detect_interpret(interpret),
     )(A, B)

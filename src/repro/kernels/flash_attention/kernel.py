@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import detect_interpret
+
 __all__ = ["flash_call"]
 
 NEG_INF = -1e30
@@ -68,7 +70,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, seq_k, true_k,
 @functools.partial(jax.jit,
                    static_argnames=("bq", "bk", "causal", "interpret", "true_k"))
 def flash_call(q: jax.Array, k: jax.Array, v: jax.Array, *, bq: int = 128,
-               bk: int = 128, causal: bool = True, interpret: bool = True,
+               bk: int = 128, causal: bool = True,
+               interpret: bool | None = None,
                true_k: int | None = None):
     """q (BH, Sq, d), k/v (BH, Sk, d) — padded to tile multiples by ops.py.
     true_k: un-padded key length (padding keys are masked)."""
@@ -88,5 +91,5 @@ def flash_call(q: jax.Array, k: jax.Array, v: jax.Array, *, bq: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, d), q.dtype),
-        interpret=interpret,
+        interpret=detect_interpret(interpret),
     )(q, k, v)

@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import detect_interpret
 from repro.kernels.flash_attention.kernel import flash_call
 
 __all__ = ["flash_attention"]
@@ -13,7 +14,7 @@ __all__ = ["flash_attention"]
 
 @partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: bool | None = None):
     """q (B, Sq, H, D); k, v (B, Sk, KV, D) with H % KV == 0 (GQA).
 
     Returns (B, Sq, H, D).  Sq/Sk padded to tile multiples internally; the
@@ -38,6 +39,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
     kp = jnp.pad(kh, ((0, 0), (0, pad_k), (0, 0)))
     vp = jnp.pad(vh, ((0, 0), (0, pad_k), (0, 0)))
     out = flash_call(qp, kp, vp, bq=bq_eff, bk=bk_eff, causal=causal,
-                     interpret=interpret, true_k=kh.shape[1])
+                     interpret=detect_interpret(interpret),
+                     true_k=kh.shape[1])
     out = out[:, :Sq]
     return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

@@ -19,8 +19,9 @@ see :func:`_check_tile_proposal` / :func:`_resident_round` /
 * check-axis-TILED fused decodes — the same four variants with H living in
   HBM (``memory_space=ANY``) and streamed tile-by-tile over the CHECK axis
   through a double-buffered VMEM scratch (``(2, bp, N)`` slots + DMA
-  semaphores), while the ``(N, bv)`` value carry stays in VMEM as the loop
-  carry: :func:`decode_fused_tiled`, :func:`decode_fused_batch_tiled`,
+  semaphores), while the lane-major ``(bv, N)`` value carry stays in VMEM
+  as the loop carry: :func:`decode_fused_tiled`,
+  :func:`decode_fused_batch_tiled`,
   :func:`decode_fused_adaptive_tiled`,
   :func:`decode_fused_batch_adaptive_tiled`.  This removes the
   whole-H-in-VMEM cap (N ≲ 2048 f32) — problem size is bounded by HBM, not
@@ -28,12 +29,13 @@ see :func:`_check_tile_proposal` / :func:`_resident_round` /
   carry, independent of ``p``.
 
 The in-kernel "scatter" is expressed MXU-style: the per-check resolution
-one-hot ``(bp, N)`` is transposed into a matmul that accumulates each
-resolved coordinate's new value — TPUs have no efficient in-kernel scatter,
-but a ``(N, bp) @ (bp, BV)`` dot is native.  Checks that resolve the same
-coordinate in the same round write consistent values (they are parity checks
-of one codeword); the kernel deterministically keeps the lowest-index
-check's value.  The tiled round preserves that rule exactly: tiles are
+one-hot ``(bp, N)`` becomes the right operand of a matmul that moves each
+resolved coordinate's new value into place — TPUs have no efficient
+in-kernel scatter, but a ``(BV, bp) @ (bp, N)`` dot is native.  Checks
+that resolve the same coordinate in the same round write consistent
+values (they are parity checks of one codeword); the kernel
+deterministically keeps the lowest-index check's value.  The tiled round
+preserves that rule exactly: tiles are
 processed in ascending check order and a coordinate takes the FIRST tile's
 resolution (within a tile, the lowest row — so the merge winner is the
 globally lowest check row, the same check the resident merge picks), and
@@ -44,7 +46,13 @@ summation order (XLA may block a tile-shaped row-sum reduction differently
 than the whole-H one).
 
 TPU notes:
-  * matmul dims padded to multiples of 128 (MXU), f32 accumulation;
+  * the flooding kernels carry values LANE-MAJOR: payload ``(V, N)`` with
+    the code axis on lanes and payload rows on sublanes, erasure mask
+    ``(1, N)`` — a scalar payload costs one 8-row tile instead of an
+    ``(N, 128)`` lane-padded column (8 MiB at N = 16384, which overran
+    VMEM and took minutes to compile); the ops.py wrappers transpose once
+    at entry and exit;
+  * all contractions run at ``Precision.HIGHEST`` (f32 on the MXU);
   * pos is computed with broadcasted_iota + max (no 1-D iota on TPU);
   * 1-D per-check outputs are materialized as (BP, 1) tiles (TPU wants >=2D);
   * resident grids re-map the same H block at every step, so H is fetched
@@ -122,24 +130,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import detect_interpret
+
 __all__ = ["check_pass", "decode_fused", "decode_fused_batch",
            "decode_fused_adaptive", "decode_fused_batch_adaptive",
            "decode_fused_tiled", "decode_fused_batch_tiled",
            "decode_fused_adaptive_tiled", "decode_fused_batch_adaptive_tiled",
            "decode_seeded", "decode_seeded_batch", "decode_seeded_adaptive",
            "decode_seeded_batch_adaptive", "seeded_h_tile",
-           "encode_seeded_fused", "decode_replay", "detect_interpret"]
+           "encode_seeded_fused", "decode_replay", "detect_interpret",
+           "interpret_only"]
 
 SEEDED_MODES = ("dense_tile", "gather")
 
 _HIGH = jax.lax.Precision.HIGHEST
+# dot_general dimension numbers contracting the last (lane) axis of both
+# operands: ``(M, N) x (K, N) -> (M, K)``, the MXU's native transposed-RHS
+# form.
+_CONTRACT_LANES = (((1,), (1,)), ((), ()))
 
 
-def detect_interpret(interpret: bool | None) -> bool:
-    """Pallas runs compiled only on TPU; anywhere else use interpret mode."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
+def interpret_only(what: str, interpret: bool) -> None:
+    """Refuse a compiled (TPU) launch of a kernel Mosaic cannot lower.
+
+    The seeded gather round, the fused seeded encode and the schedule
+    replay index VMEM values at arbitrary positions (``x[idx]``) and the
+    replay also slices at unaligned dynamic offsets; the TPU compiler
+    rejects both ("Only 2D gather is supported", no ``dynamic_slice``
+    lowering).  They stay correct in interpret mode, so CPU runs keep
+    them; on TPU the caller gets this error instead of a compiler trace.
+    """
+    if not interpret:
+        raise NotImplementedError(
+            f"{what} does not compile for TPU: Mosaic cannot lower its "
+            "arbitrary-index in-kernel gathers.  It runs only in interpret "
+            "mode (off-TPU); on TPU use the dense-tile / tiled kernels or "
+            "the XLA replay executors.")
 
 
 def _check_kernel(H_ref, vals_ref, erased_ref, sums_ref, cnt_ref, pos_ref,
@@ -208,9 +234,11 @@ def check_pass(H: jax.Array, values: jax.Array, erased_f: jax.Array, *,
 def _check_tile_proposal(H, known, e):
     """One check tile's resolution proposal against the ROUND-START state.
 
-    ``H (bp, N)`` is a tile of check rows; ``known (N, BV) = vals·(1-e)``
-    and ``e (N, 1)`` are the round-start known values / erasure mask.
-    Returns ``(resolved (N, 1) ∈ {0, 1}, scattered (N, BV))``: which
+    ``H (bp, N)`` is a tile of check rows; ``known (BV, N) = vals·(1-e)``
+    and ``e (1, N)`` are the round-start known values / erasure mask, with
+    the code axis on LANES (the payload rows sit on sublanes, so a scalar
+    payload costs one 8-row tile, not an ``(N, 128)`` lane-padded column).
+    Returns ``(resolved (1, N) ∈ {0, 1}, scattered (BV, N))``: which
     coordinates THIS tile resolves and the values it writes, with the
     lowest row in the tile winning intra-tile ties.  This is the ONE
     implementation of the flooding-round check/variable math — every fused
@@ -218,24 +246,34 @@ def _check_tile_proposal(H, known, e):
     its round from it, so all variants follow the identical erasure
     trajectory (same solvability decisions, same resolved neighbour, same
     lowest-index-check tie-break).
+
+    Counts, positions and coefficients are exact: ``cnt`` is a lane sum of
+    0/1 flags, ``pos`` a lane max of column indices, and ``coeff`` a
+    ``HIGHEST``-precision contraction of a one-nonzero row against ones
+    (the multi-pass f32 matmul reconstructs a single f32 term exactly).
+    Only the value sums carry f32 rounding.
     """
-    Hb = (H != 0.0).astype(jnp.float32)
+    Hb = H != 0.0
     col = jax.lax.broadcasted_iota(jnp.int32, H.shape, 1)  # (bp, N)
     row = jax.lax.broadcasted_iota(jnp.int32, H.shape, 0)  # (bp, N)
-    cnt = jax.lax.dot(Hb, e, precision=_HIGH)  # (bp, 1)
-    solvable = cnt[:, 0] == 1.0  # (bp,)
-    sums = jax.lax.dot(H, known, precision=_HIGH)  # (bp, BV)
-    emask = (Hb * e[:, 0][None, :]) > 0.0
-    pos = jnp.max(jnp.where(emask, col, -1), axis=1)  # (bp,)
-    onehot = (col == pos[:, None]) & solvable[:, None]  # (bp, N) bool
-    coeff = jnp.sum(H * onehot.astype(jnp.float32), axis=1)  # (bp,)
-    new_val = -sums / jnp.where(coeff == 0.0, 1.0, coeff)[:, None]
+    emask = Hb & (e > 0.0)                                  # (bp, N)
+    cnt = jnp.sum(emask.astype(jnp.float32), axis=1, keepdims=True)
+    solvable = cnt == 1.0                                   # (bp, 1)
+    pos = jnp.max(jnp.where(emask, col, -1), axis=1, keepdims=True)
+    onehot = (col == pos) & solvable                        # (bp, N) bool
+    # (BV, bp) contractions over the lane (code) axis of both operands
+    sums = jax.lax.dot_general(known, H, _CONTRACT_LANES, precision=_HIGH)
+    coeff = jax.lax.dot_general(jnp.ones_like(known),
+                                jnp.where(onehot, H, 0.0), _CONTRACT_LANES,
+                                precision=_HIGH)
+    new_val = -sums / jnp.where(coeff == 0.0, 1.0, coeff)   # (BV, bp)
     # Several checks may resolve the same coordinate; keep the
     # lowest-index check's (consistent) value deterministically.
-    winner_row = jnp.min(jnp.where(onehot, row, H.shape[0]), axis=0)  # (N,)
-    winner = (onehot & (row == winner_row[None, :])).astype(jnp.float32)
-    resolved = jnp.max(winner, axis=0)[:, None]  # (N, 1) ∈ {0, 1}
-    scattered = jax.lax.dot(winner.T, new_val, precision=_HIGH)  # (N, BV)
+    winner_row = jnp.min(jnp.where(onehot, row, H.shape[0]), axis=0,
+                         keepdims=True)                     # (1, N)
+    winner = (onehot & (row == winner_row)).astype(jnp.float32)
+    resolved = jnp.max(winner, axis=0, keepdims=True)       # (1, N)
+    scattered = jax.lax.dot(new_val, winner, precision=_HIGH)  # (BV, N)
     return resolved, scattered
 
 
@@ -351,37 +389,37 @@ def _decode_kernel(H_ref, vals_ref, erased_ref, out_vals_ref, out_erased_ref,
 
 @functools.partial(jax.jit, static_argnames=("iters", "bv", "interpret"))
 def decode_fused(H: jax.Array, values: jax.Array, erased_f: jax.Array, *,
-                 iters: int, bv: int = 128, interpret: bool | None = None):
+                 iters: int, bv: int = 8, interpret: bool | None = None):
     """Whole fixed-``iters`` decode in one ``pallas_call``.
 
     Inputs (already padded by ops.py): H (p, N) f32 with p % 8 == 0 and
-    N % 128 == 0; values (N, V) f32 with V % bv == 0; erased_f (N, 1) f32.
+    N % 128 == 0; values (V, N) f32 with V % bv == 0; erased_f (1, N) f32.
 
     ``interpret=None`` = backend-detected (compiled on TPU, else interpret).
 
-    Returns (values (N, V) f32, erased (N, 1) f32) after ``iters`` rounds.
+    Returns (values (V, N) f32, erased (1, N) f32) after ``iters`` rounds.
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    V = values.shape[1]
+    V = values.shape[0]
     grid = (V // bv,)
     return pl.pallas_call(
         functools.partial(_decode_kernel, iters=iters),
         grid=grid,
         in_specs=[
             pl.BlockSpec((p, N), lambda j: (0, 0)),  # H: resident, reused over j
-            pl.BlockSpec((N, bv), lambda j: (0, j)),  # payload slice
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),   # initial erasure mask
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),  # payload slice
+            pl.BlockSpec((1, N), lambda j: (0, 0)),   # initial erasure mask
         ],
         out_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
             # every grid step recomputes the identical erasure trajectory and
             # rewrites the same block — benign (sequential grid on TPU).
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, V), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((V, N), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
         interpret=interpret,
     )(H, values, erased_f)
@@ -400,12 +438,12 @@ def _decode_batch_kernel(H_ref, vals_ref, erased_ref, out_vals_ref,
 
 @functools.partial(jax.jit, static_argnames=("iters", "bv", "interpret"))
 def decode_fused_batch(H: jax.Array, values: jax.Array, erased_f: jax.Array,
-                       *, iters: int, bv: int = 128,
+                       *, iters: int, bv: int = 8,
                        interpret: bool | None = None):
     """``B`` independent erasure patterns, one ``pallas_call``.
 
     Inputs (already padded by ops.py): H (p, N) f32 with p % 8 == 0 and
-    N % 128 == 0; values (B, N, V) f32 with V % bv == 0; erased_f (B, N, 1)
+    N % 128 == 0; values (B, V, N) f32 with V % bv == 0; erased_f (B, 1, N)
     f32.  The grid is ``(B, V // bv)``; the H block's index map is constant,
     so H is fetched into VMEM once and stays resident while each query's
     payload/mask tiles stream through — the per-query marginal cost is the
@@ -413,30 +451,30 @@ def decode_fused_batch(H: jax.Array, values: jax.Array, erased_f: jax.Array,
 
     ``interpret=None`` = backend-detected (compiled on TPU, else interpret).
 
-    Returns (values (B, N, V) f32, erased (B, N, 1) f32).
+    Returns (values (B, V, N) f32, erased (B, 1, N) f32).
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    B, _, V = values.shape
+    B, V, _ = values.shape
     grid = (B, V // bv)
     return pl.pallas_call(
         functools.partial(_decode_batch_kernel, iters=iters),
         grid=grid,
         in_specs=[
             pl.BlockSpec((p, N), lambda b, j: (0, 0)),      # H: resident
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
             # grid steps sharing a batch index recompute the identical
             # trajectory and rewrite the same block — benign (sequential
             # grid on TPU).
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, N, V), jnp.float32),
-            jax.ShapeDtypeStruct((B, N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, V, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, N), jnp.float32),
         ],
         interpret=interpret,
     )(H, values, erased_f)
@@ -458,38 +496,38 @@ def _decode_adaptive_kernel(H_ref, vals_ref, erased_ref, out_vals_ref,
 @functools.partial(jax.jit, static_argnames=("max_iters", "bv", "interpret"))
 def decode_fused_adaptive(H: jax.Array, values: jax.Array,
                           erased_f: jax.Array, *, max_iters: int,
-                          bv: int = 128, interpret: bool | None = None):
+                          bv: int = 8, interpret: bool | None = None):
     """Early-exit decode in one launch: in-kernel ``while_loop`` that stops
     as soon as a round makes no progress (or nothing is erased), exactly the
     ``peel_decode_adaptive`` stopping rule — "decoding effort tracks the
     number of stragglers" without leaving the kernel.
 
     Inputs (already padded by ops.py) as for :func:`decode_fused`.  Returns
-    (values (N, V) f32, erased (N, 1) f32, rounds (1, 1) i32).  The erasure
+    (values (V, N) f32, erased (1, N) f32, rounds (1, 1) i32).  The erasure
     trajectory depends only on H and the initial mask, so every payload
     slice exits after the identical round count and the shared rounds output
     is written consistently by each grid step.
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    V = values.shape[1]
+    V = values.shape[0]
     grid = (V // bv,)
     return pl.pallas_call(
         functools.partial(_decode_adaptive_kernel, max_iters=max_iters),
         grid=grid,
         in_specs=[
             pl.BlockSpec((p, N), lambda j: (0, 0)),  # H: resident
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
             pl.BlockSpec((1, 1), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, V), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((V, N), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
@@ -504,20 +542,20 @@ def _decode_batch_adaptive_kernel(H_ref, vals_ref, erased_ref, budget_ref,
                                   out_rounds_ref):
     round_body = _resident_round(H_ref[...])  # H shared across the whole batch
     vals, e, d = _adaptive_loop(round_body, vals_ref[0], erased_ref[0],
-                                budget_ref[0, 0])  # THIS slot's round budget
+                                budget_ref[0, 0, 0])  # this slot's budget
     out_vals_ref[0] = vals
     out_erased_ref[0] = e
-    out_rounds_ref[...] = jnp.full((1, 1), d, jnp.int32)
+    out_rounds_ref[...] = jnp.full((1, 1, 1), d, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bv", "interpret"))
 def decode_fused_batch_adaptive(H: jax.Array, values: jax.Array,
                                 erased_f: jax.Array, budgets: jax.Array, *,
-                                bv: int = 128, interpret: bool | None = None):
+                                bv: int = 8, interpret: bool | None = None):
     """Per-slot adaptive decode of ``B`` independent patterns, ONE launch.
 
     Inputs (already padded by ops.py): H (p, N) f32 with p % 8 == 0 and
-    N % 128 == 0; values (B, N, V) f32 with V % bv == 0; erased_f (B, N, 1)
+    N % 128 == 0; values (B, V, N) f32 with V % bv == 0; erased_f (B, 1, N)
     f32; budgets (B, 1) int32 — each slot's round budget.  The grid is
     ``(B, V // bv)`` with the H block's index map constant, so H is fetched
     into VMEM once and stays resident across the whole batch while per-slot
@@ -530,36 +568,37 @@ def decode_fused_batch_adaptive(H: jax.Array, values: jax.Array,
 
     ``interpret=None`` = backend-detected (compiled on TPU, else interpret).
 
-    Returns (values (B, N, V) f32, erased (B, N, 1) f32, rounds (B, 1) i32).
+    Returns (values (B, V, N) f32, erased (B, 1, N) f32, rounds (B, 1) i32).
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    B, _, V = values.shape
+    B, V, _ = values.shape
     grid = (B, V // bv)
-    return pl.pallas_call(
+    vals, erased, rounds = pl.pallas_call(
         _decode_batch_adaptive_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((p, N), lambda b, j: (0, 0)),      # H: resident
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),      # slot budget
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),      # slot budget
         ],
         out_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
             # grid steps sharing a batch index recompute the identical
             # trajectory (it depends only on H, the mask, and the budget)
             # and rewrite the same block — benign (sequential grid on TPU).
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, N, V), jnp.float32),
-            jax.ShapeDtypeStruct((B, N, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, V, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(H, values, erased_f, budgets)
+    )(H, values, erased_f, budgets[:, :, None])
+    return vals, erased, rounds[:, :, 0]
 
 
 # ---------------------------------------------- check-axis-tiled decodes --
@@ -598,38 +637,38 @@ def _decode_tiled_kernel(H_hbm, vals_ref, erased_ref, out_vals_ref,
 
 @functools.partial(jax.jit, static_argnames=("iters", "bp", "bv", "interpret"))
 def decode_fused_tiled(H: jax.Array, values: jax.Array, erased_f: jax.Array,
-                       *, iters: int, bp: int = 128, bv: int = 128,
+                       *, iters: int, bp: int = 128, bv: int = 8,
                        interpret: bool | None = None):
     """Fixed-``iters`` decode with H STREAMED over check tiles.
 
     Inputs (already padded by ops.py): H (p, N) f32 with p % bp == 0 and
-    N % 128 == 0; values (N, V) f32 with V % bv == 0; erased_f (N, 1) f32.
+    N % 128 == 0; values (V, N) f32 with V % bv == 0; erased_f (1, N) f32.
     Same trajectory and output contract as :func:`decode_fused`; the VMEM
-    working set is ``2·bp·N`` stream slots + the ``(N, bv)`` carry instead
+    working set is ``2·bp·N`` stream slots + the ``(bv, N)`` carry instead
     of the whole ``(p, N)`` H — this is the variant ``backend="auto"``
     routes to when ``core/decoder.vmem_bytes_estimate`` says the resident
     kernel will not fit.
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    V = values.shape[1]
+    V = values.shape[0]
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (V // bv,)
     return pl.pallas_call(
         functools.partial(_decode_tiled_kernel, iters=iters, bp=bp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # H: stays in HBM
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),     # H: stays in HBM
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, V), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((V, N), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
         scratch_shapes=_tiled_scratch(bp, N),
         interpret=interpret,
@@ -650,12 +689,12 @@ def _decode_batch_tiled_kernel(H_hbm, vals_ref, erased_ref, out_vals_ref,
 @functools.partial(jax.jit, static_argnames=("iters", "bp", "bv", "interpret"))
 def decode_fused_batch_tiled(H: jax.Array, values: jax.Array,
                              erased_f: jax.Array, *, iters: int,
-                             bp: int = 128, bv: int = 128,
+                             bp: int = 128, bv: int = 8,
                              interpret: bool | None = None):
     """``B`` independent patterns with H streamed over check tiles.
 
-    Same contract as :func:`decode_fused_batch` (values (B, N, V), erased_f
-    (B, N, 1), both padded); the grid runs over ``(B, V // bv)`` and every
+    Same contract as :func:`decode_fused_batch` (values (B, V, N), erased_f
+    (B, 1, N), both padded); the grid runs over ``(B, V // bv)`` and every
     grid step re-streams the H tiles from HBM while its slot's payload/mask
     tiles live in VMEM.  (On the batch axis the resident kernel amortizes
     the H fetch across slots; the tiled kernel instead bounds VMEM by
@@ -663,24 +702,24 @@ def decode_fused_batch_tiled(H: jax.Array, values: jax.Array,
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    B, _, V = values.shape
+    B, V, _ = values.shape
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (B, V // bv)
     return pl.pallas_call(
         functools.partial(_decode_batch_tiled_kernel, iters=iters, bp=bp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # H: stays in HBM
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),     # H: stays in HBM
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, N, V), jnp.float32),
-            jax.ShapeDtypeStruct((B, N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, V, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, N), jnp.float32),
         ],
         scratch_shapes=_tiled_scratch(bp, N),
         interpret=interpret,
@@ -704,19 +743,19 @@ def _decode_adaptive_tiled_kernel(H_hbm, vals_ref, erased_ref, out_vals_ref,
                    static_argnames=("max_iters", "bp", "bv", "interpret"))
 def decode_fused_adaptive_tiled(H: jax.Array, values: jax.Array,
                                 erased_f: jax.Array, *, max_iters: int,
-                                bp: int = 128, bv: int = 128,
+                                bp: int = 128, bv: int = 8,
                                 interpret: bool | None = None):
     """Early-exit decode with H streamed over check tiles.
 
     Same stopping rule, trajectory, and output contract as
-    :func:`decode_fused_adaptive` (values (N, V), erased (N, 1),
+    :func:`decode_fused_adaptive` (values (V, N), erased (1, N),
     rounds (1, 1)); the in-kernel ``while_loop`` wraps the streamed round,
     so an early exit also stops the H streaming — decode bandwidth tracks
     the realized straggler load, not the worst case.
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    V = values.shape[1]
+    V = values.shape[0]
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (V // bv,)
     return pl.pallas_call(
@@ -724,18 +763,18 @@ def decode_fused_adaptive_tiled(H: jax.Array, values: jax.Array,
                           bp=bp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # H: stays in HBM
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),     # H: stays in HBM
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
             pl.BlockSpec((1, 1), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, V), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((V, N), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         scratch_shapes=_tiled_scratch(bp, N),
@@ -750,18 +789,18 @@ def _decode_batch_adaptive_tiled_kernel(H_hbm, vals_ref, erased_ref,
     round_body, prime, drain = _streamed_round(H_hbm, h_scratch, sem, bp=bp)
     prime()
     vals, e, d = _adaptive_loop(round_body, vals_ref[0], erased_ref[0],
-                                budget_ref[0, 0])  # THIS slot's round budget
+                                budget_ref[0, 0, 0])  # this slot's budget
     drain(d)
     out_vals_ref[0] = vals
     out_erased_ref[0] = e
-    out_rounds_ref[...] = jnp.full((1, 1), d, jnp.int32)
+    out_rounds_ref[...] = jnp.full((1, 1, 1), d, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bp", "bv", "interpret"))
 def decode_fused_batch_adaptive_tiled(H: jax.Array, values: jax.Array,
                                       erased_f: jax.Array,
                                       budgets: jax.Array, *, bp: int = 128,
-                                      bv: int = 128,
+                                      bv: int = 8,
                                       interpret: bool | None = None):
     """Per-slot adaptive decode of ``B`` patterns with H streamed per slot.
 
@@ -772,38 +811,39 @@ def decode_fused_batch_adaptive_tiled(H: jax.Array, values: jax.Array,
     """
     interpret = detect_interpret(interpret)
     p, N = H.shape
-    B, _, V = values.shape
+    B, V, _ = values.shape
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (B, V // bv)
-    return pl.pallas_call(
+    vals, erased, rounds = pl.pallas_call(
         functools.partial(_decode_batch_adaptive_tiled_kernel, bp=bp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # H: stays in HBM
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),      # slot budget
+            pl.BlockSpec(memory_space=pl.ANY),     # H: stays in HBM
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),      # slot budget
         ],
         out_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, N, V), jnp.float32),
-            jax.ShapeDtypeStruct((B, N, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, V, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
         scratch_shapes=_tiled_scratch(bp, N),
         interpret=interpret,
-    )(H, values, erased_f, budgets)
+    )(H, values, erased_f, budgets[:, :, None])
+    return vals, erased, rounds[:, :, 0]
 
 
 # --------------------------------------------------- seeded tiled decodes --
 #
 # The same four contracts with the DMA'd H scratch replaced by in-register
 # tile GENERATION: no H operand, no stream slots, no semaphores — the only
-# HBM traffic is the (N, bv) payload carry and masks.  The structure spec
+# HBM traffic is the (bv, N) payload carry and masks.  The structure spec
 # (repro.core.ldpc.SeededStructure — plain ints/tuples, hashable) is a
 # STATIC argument, so the per-layer affine constants are compiled into the
 # kernel and tile regeneration is pure VPU arithmetic on iotas.
@@ -851,7 +891,7 @@ def _seeded_edge_weight(spec, rows, s: int):
     reference (``repro.core.ldpc._structure_rows_raw``)."""
     edge = (rows * spec.row_weight + s).astype(jnp.uint32)
     u = _mix32_jnp(edge ^ jnp.uint32(spec.wseed))
-    sign = 1.0 - 2.0 * (u & 1).astype(jnp.float32)
+    sign = 1.0 - 2.0 * (u & 1).astype(jnp.int32).astype(jnp.float32)
     m = (u >> 9).astype(jnp.int32).astype(jnp.float32)   # [0, 2^23)
     return sign * (1.0 + m * jnp.float32(2.0 ** -23))    # exact f32
 
@@ -969,8 +1009,11 @@ def _seeded_gather_round(spec, *, bp: int, p_pad: int, n_pad: int):
 
     def round_body(vals, e, t_round):
         del t_round                        # no pipeline position to keep
-        known = vals * (1.0 - e)
-        e_flat = e[:, 0]                                      # (n_pad,)
+        # the check and variable passes below run column-major ((rows, 1)
+        # per-check / (n_pad, 1) per-column vectors); the carry is
+        # lane-major like every other kernel's, transposed at the edges
+        known = (vals * (1.0 - e)).T                          # (n_pad, BV)
+        e_flat = e[0]                                         # (n_pad,)
         col2 = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)
 
         def tile_step(j, carry):
@@ -1016,6 +1059,7 @@ def _seeded_gather_round(spec, *, bp: int, p_pad: int, n_pad: int):
                 t_res = jnp.where(take, 1.0, t_res)
                 t_scat = jnp.where(take, nv, t_scat)
 
+            t_res, t_scat = t_res.T, t_scat.T                 # lane-major
             take = (t_res > 0.0) & (resolved <= 0.0)
             return (jnp.maximum(resolved, t_res),
                     jnp.where(take, t_scat, scattered))
@@ -1055,7 +1099,7 @@ def _seeded_p_pad(spec, bp: int) -> int:
 
 def _decode_seeded_kernel(vals_ref, erased_ref, out_vals_ref, out_erased_ref,
                           *, spec, iters: int, bp: int, mode: str):
-    N = vals_ref.shape[0]
+    N = vals_ref.shape[1]
     round_body = _seeded_round_for(spec, mode, bp=bp,
                                    p_pad=_seeded_p_pad(spec, bp), n_pad=N)
     vals, e = _fixed_loop(round_body, vals_ref[...], erased_ref[...], iters)
@@ -1067,22 +1111,23 @@ def _decode_seeded_kernel(vals_ref, erased_ref, out_vals_ref, out_erased_ref,
                    static_argnames=("spec", "iters", "bp", "bv", "interpret",
                                     "mode"))
 def decode_seeded(spec, values: jax.Array, erased_f: jax.Array, *,
-                  iters: int, bp: int = 128, bv: int = 128,
+                  iters: int, bp: int = 128, bv: int = 8,
                   interpret: bool | None = None, mode: str = "dense_tile"):
     """Fixed-``iters`` decode with H REGENERATED from the seed per tile.
 
-    Inputs (already padded by ops.py): values (N, V) f32 with N % 128 == 0
+    Inputs (already padded by ops.py): values (V, N) f32 with N % 128 == 0
     covering ``spec.cols`` (padded columns are all-zero in the generated
-    tiles, so they never move), erased_f (N, 1) f32.  ``spec`` is the
+    tiles, so they never move), erased_f (1, N) f32.  ``spec`` is the
     static :class:`repro.core.ldpc.SeededStructure`.  Same trajectory and
     output contract as :func:`decode_fused` / :func:`decode_fused_tiled`
     on the materialized H of the same code; the VMEM working set is ONE
-    generated ``(bp, N)`` tile plus the ``(N, bv)`` carry, and H
+    generated ``(bp, N)`` tile plus the ``(bv, N)`` carry, and H
     contributes ZERO bytes of operand traffic.
     """
     interpret = detect_interpret(interpret)
-    N = values.shape[0]
-    V = values.shape[1]
+    if mode == "gather":
+        interpret_only("seeded_mode='gather'", interpret)
+    V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (V // bv,)
     return pl.pallas_call(
@@ -1090,16 +1135,16 @@ def decode_seeded(spec, values: jax.Array, erased_f: jax.Array, *,
                           bp=bp, mode=mode),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, V), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((V, N), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
         interpret=interpret,
     )(values, erased_f)
@@ -1108,7 +1153,7 @@ def decode_seeded(spec, values: jax.Array, erased_f: jax.Array, *,
 def _decode_seeded_batch_kernel(vals_ref, erased_ref, out_vals_ref,
                                 out_erased_ref, *, spec, iters: int, bp: int,
                                 mode: str):
-    N = vals_ref.shape[1]
+    N = vals_ref.shape[2]
     round_body = _seeded_round_for(spec, mode, bp=bp,
                                    p_pad=_seeded_p_pad(spec, bp), n_pad=N)
     vals, e = _fixed_loop(round_body, vals_ref[0], erased_ref[0], iters)
@@ -1120,18 +1165,20 @@ def _decode_seeded_batch_kernel(vals_ref, erased_ref, out_vals_ref,
                    static_argnames=("spec", "iters", "bp", "bv", "interpret",
                                     "mode"))
 def decode_seeded_batch(spec, values: jax.Array, erased_f: jax.Array, *,
-                        iters: int, bp: int = 128, bv: int = 128,
+                        iters: int, bp: int = 128, bv: int = 8,
                         interpret: bool | None = None,
                         mode: str = "dense_tile"):
     """``B`` independent patterns, H regenerated from the seed per tile.
 
-    Same contract as :func:`decode_fused_batch_tiled` (values (B, N, V),
-    erased_f (B, N, 1), both padded) minus the H operand: every grid step
+    Same contract as :func:`decode_fused_batch_tiled` (values (B, V, N),
+    erased_f (B, 1, N), both padded) minus the H operand: every grid step
     re-generates the tiles instead of re-streaming them, so the per-slot
     marginal HBM traffic is the payload alone.
     """
     interpret = detect_interpret(interpret)
-    B, N, V = values.shape
+    if mode == "gather":
+        interpret_only("seeded_mode='gather'", interpret)
+    B, V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (B, V // bv)
     return pl.pallas_call(
@@ -1139,16 +1186,16 @@ def decode_seeded_batch(spec, values: jax.Array, erased_f: jax.Array, *,
                           iters=iters, bp=bp, mode=mode),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, N, V), jnp.float32),
-            jax.ShapeDtypeStruct((B, N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, V, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, N), jnp.float32),
         ],
         interpret=interpret,
     )(values, erased_f)
@@ -1157,7 +1204,7 @@ def decode_seeded_batch(spec, values: jax.Array, erased_f: jax.Array, *,
 def _decode_seeded_adaptive_kernel(vals_ref, erased_ref, out_vals_ref,
                                    out_erased_ref, out_rounds_ref, *, spec,
                                    max_iters: int, bp: int, mode: str):
-    N = vals_ref.shape[0]
+    N = vals_ref.shape[1]
     round_body = _seeded_round_for(spec, mode, bp=bp,
                                    p_pad=_seeded_p_pad(spec, bp), n_pad=N)
     vals, e, d = _adaptive_loop(round_body, vals_ref[...], erased_ref[...],
@@ -1170,16 +1217,18 @@ def _decode_seeded_adaptive_kernel(vals_ref, erased_ref, out_vals_ref,
 @functools.partial(jax.jit, static_argnames=("spec", "max_iters", "bp", "bv",
                                              "interpret", "mode"))
 def decode_seeded_adaptive(spec, values: jax.Array, erased_f: jax.Array, *,
-                           max_iters: int, bp: int = 128, bv: int = 128,
+                           max_iters: int, bp: int = 128, bv: int = 8,
                            interpret: bool | None = None,
                            mode: str = "dense_tile"):
     """Early-exit decode with seed-regenerated tiles: an early exit stops
     the tile regeneration compute the way it stops the tiled kernel's H
     streaming.  Same stopping rule and outputs as
-    :func:`decode_fused_adaptive` (values (N, V), erased (N, 1), rounds
+    :func:`decode_fused_adaptive` (values (V, N), erased (1, N), rounds
     (1, 1))."""
     interpret = detect_interpret(interpret)
-    N, V = values.shape
+    if mode == "gather":
+        interpret_only("seeded_mode='gather'", interpret)
+    V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (V // bv,)
     return pl.pallas_call(
@@ -1187,17 +1236,17 @@ def decode_seeded_adaptive(spec, values: jax.Array, erased_f: jax.Array, *,
                           max_iters=max_iters, bp=bp, mode=mode),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((N, bv), lambda j: (0, j)),
-            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((bv, N), lambda j: (j, 0)),
+            pl.BlockSpec((1, N), lambda j: (0, 0)),
             pl.BlockSpec((1, 1), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, V), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((V, N), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
@@ -1208,21 +1257,21 @@ def _decode_seeded_batch_adaptive_kernel(vals_ref, erased_ref, budget_ref,
                                          out_vals_ref, out_erased_ref,
                                          out_rounds_ref, *, spec, bp: int,
                                          mode: str):
-    N = vals_ref.shape[1]
+    N = vals_ref.shape[2]
     round_body = _seeded_round_for(spec, mode, bp=bp,
                                    p_pad=_seeded_p_pad(spec, bp), n_pad=N)
     vals, e, d = _adaptive_loop(round_body, vals_ref[0], erased_ref[0],
-                                budget_ref[0, 0])  # THIS slot's round budget
+                                budget_ref[0, 0, 0])  # this slot's budget
     out_vals_ref[0] = vals
     out_erased_ref[0] = e
-    out_rounds_ref[...] = jnp.full((1, 1), d, jnp.int32)
+    out_rounds_ref[...] = jnp.full((1, 1, 1), d, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "bp", "bv", "interpret",
                                              "mode"))
 def decode_seeded_batch_adaptive(spec, values: jax.Array,
                                  erased_f: jax.Array, budgets: jax.Array, *,
-                                 bp: int = 128, bv: int = 128,
+                                 bp: int = 128, bv: int = 8,
                                  interpret: bool | None = None,
                                  mode: str = "dense_tile"):
     """Per-slot adaptive decode of ``B`` patterns, seed-regenerated tiles.
@@ -1233,30 +1282,33 @@ def decode_seeded_batch_adaptive(spec, values: jax.Array,
     touches HBM for H.
     """
     interpret = detect_interpret(interpret)
-    B, N, V = values.shape
+    if mode == "gather":
+        interpret_only("seeded_mode='gather'", interpret)
+    B, V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (B, V // bv)
-    return pl.pallas_call(
+    vals, erased, rounds = pl.pallas_call(
         functools.partial(_decode_seeded_batch_adaptive_kernel, spec=spec,
                           bp=bp, mode=mode),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),      # slot budget
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),      # slot budget
         ],
         out_specs=[
-            pl.BlockSpec((1, N, bv), lambda b, j: (b, 0, j)),
-            pl.BlockSpec((1, N, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, bv, N), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, N, V), jnp.float32),
-            jax.ShapeDtypeStruct((B, N, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, V, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(values, erased_f, budgets)
+    )(values, erased_f, budgets[:, :, None])
+    return vals, erased, rounds[:, :, 0]
 
 
 # ----------------------------------------------------- seeded fused encode --
@@ -1330,6 +1382,7 @@ def encode_seeded_fused(st, y: jax.Array, row0: jax.Array, *, n_out: int,
     materialized anywhere.
     """
     interpret = detect_interpret(interpret)
+    interpret_only("the fused seeded encode kernel", interpret)
     K_pad, V = y.shape
     if K_pad % 128 or V % bv or K_pad < st.cols or n_out % bo or bo % 8:
         raise ValueError(
@@ -1448,6 +1501,7 @@ def decode_replay(nidx: jax.Array, w: jax.Array, coeff: jax.Array,
     Returns (values (n_pad, V) f32, erased (n_pad, 1) f32).
     """
     interpret = detect_interpret(interpret)
+    interpret_only("the fused replay kernel", interpret)
     n_pad, V = values.shape
     S, r_max = nidx.shape
     grid = (V // bv,)

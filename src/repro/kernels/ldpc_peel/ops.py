@@ -132,15 +132,44 @@ def peel_round_pallas(H, values, erased, *, interpret: bool | None = None,
                             bp=bp, bv=bv)
 
 
-def _pad_operands(H, vals, erased_f, bv):
-    """Pad ONCE for a whole fused decode: N → multiple of 128 (lanes),
-    p → multiple of 8 (sublanes), V → multiple of bv (payload tile).
-    Padded coordinates are "known" zeros on zero H columns/rows: never
-    counted, never solvable, never written."""
-    Hp = pad_axis_to(pad_axis_to(H.astype(jnp.float32), 8, 0), 128, 1)
-    vp = pad_axis_to(pad_axis_to(vals.astype(jnp.float32), 128, -2), bv, -1)
-    ep = pad_axis_to(erased_f, 128, -2)
-    return Hp, vp, ep
+def _payload_rows(V: int, bv: int) -> int:
+    """The kernels' payload tile height: ``bv`` rounded down to whole
+    8-row sublane tiles, and no taller than ``V`` rounded up to one."""
+    bv = max(8, bv - bv % 8)
+    return min(bv, V + (-V) % 8)
+
+
+def _lanes_in(vals, erased, bv):
+    """``(…, N, V)`` payload + ``(…, N)`` bool mask → the kernels'
+    LANE-MAJOR operands, padded once: values ``(…, Vp, Np)`` and mask
+    ``(…, 1, Np)`` with the code axis on lanes (N → multiple of 128) and
+    the payload on sublanes (V → whole ``bv`` tiles).  Returns ``(vp, ep,
+    bv)`` with ``bv`` the payload tile height the kernels take.  Padded
+    coordinates are "known" zeros on zero H columns: never counted, never
+    solvable, never written."""
+    bv = _payload_rows(vals.shape[-1], bv)
+    vt = jnp.swapaxes(vals.astype(jnp.float32), -1, -2)
+    vp = pad_axis_to(pad_axis_to(vt, 128, -1), bv, -2)
+    ep = pad_axis_to(erased.astype(jnp.float32)[..., None, :], 128, -1)
+    return vp, ep, bv
+
+
+def _lanes_out(out_v, out_e, N: int, V: int, dtype):
+    """Inverse of :func:`_lanes_in`: unpad and return ``(…, N, V)`` values
+    and the ``(…, N)`` bool erasure mask."""
+    vals = jnp.swapaxes(out_v[..., :V, :N], -1, -2).astype(dtype)
+    return vals, out_e[..., 0, :N] > 0.0
+
+
+def _pad_operands(H, vals, erased, bv, bp: int = 8):
+    """Pad ONCE for a whole fused decode: H's p → multiple of ``bp`` (8
+    sublanes for the resident kernels; the streamed tile height for the
+    tiled ones, so every tile is full — ragged check-tile edges become
+    all-zero rows: never counted, never solvable, never written) and
+    N → multiple of 128 (lanes); the payload and mask as in
+    :func:`_lanes_in`."""
+    Hp = pad_axis_to(pad_axis_to(H.astype(jnp.float32), bp, 0), 128, 1)
+    return (Hp, *_lanes_in(vals, erased, bv))
 
 
 @partial(jax.jit, static_argnames=("iters", "interpret", "bv"))
@@ -150,11 +179,10 @@ def _peel_decode_impl(H, values, erased, *, iters: int, interpret: bool,
     vals = values[:, None] if squeeze else values
     N, V = vals.shape
 
-    Hp, vp, ep = _pad_operands(H, vals, erased.astype(jnp.float32)[:, None], bv)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv)
     out_v, out_e = decode_fused(Hp, vp, ep, iters=iters,
-                                bv=min(bv, vp.shape[1]), interpret=interpret)
-    out_vals = out_v[:N, :V].astype(vals.dtype)
-    out_erased = out_e[:N, 0] > 0.0
+                                bv=bv_, interpret=interpret)
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, 0]
     return out_vals, out_erased
@@ -179,13 +207,11 @@ def _peel_decode_batch_impl(H, values, erased, *, iters: int, interpret: bool,
     vals = values[:, :, None] if squeeze else values
     B, N, V = vals.shape
 
-    Hp, vp, ep = _pad_operands(H, vals,
-                               erased.astype(jnp.float32)[:, :, None], bv)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv)
     out_v, out_e = decode_fused_batch(Hp, vp, ep, iters=iters,
-                                      bv=min(bv, vp.shape[2]),
+                                      bv=bv_,
                                       interpret=interpret)
-    out_vals = out_v[:, :N, :V].astype(vals.dtype)
-    out_erased = out_e[:, :N, 0] > 0.0
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, :, 0]
     return out_vals, out_erased
@@ -211,12 +237,11 @@ def _peel_decode_adaptive_impl(H, values, erased, *, max_iters: int,
     vals = values[:, None] if squeeze else values
     N, V = vals.shape
 
-    Hp, vp, ep = _pad_operands(H, vals, erased.astype(jnp.float32)[:, None], bv)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv)
     out_v, out_e, rounds = decode_fused_adaptive(
-        Hp, vp, ep, max_iters=max_iters, bv=min(bv, vp.shape[1]),
+        Hp, vp, ep, max_iters=max_iters, bv=bv_,
         interpret=interpret)
-    out_vals = out_v[:N, :V].astype(vals.dtype)
-    out_erased = out_e[:N, 0] > 0.0
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, 0]
     return out_vals, out_erased, rounds[0, 0]
@@ -243,13 +268,11 @@ def _peel_decode_batch_adaptive_impl(H, values, erased, budgets, *,
     vals = values[:, :, None] if squeeze else values
     B, N, V = vals.shape
 
-    Hp, vp, ep = _pad_operands(H, vals,
-                               erased.astype(jnp.float32)[:, :, None], bv)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv)
     out_v, out_e, rounds = decode_fused_batch_adaptive(
         Hp, vp, ep, budgets.astype(jnp.int32)[:, None],
-        bv=min(bv, vp.shape[2]), interpret=interpret)
-    out_vals = out_v[:, :N, :V].astype(vals.dtype)
-    out_erased = out_e[:, :N, 0] > 0.0
+        bv=bv_, interpret=interpret)
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, :, 0]
     return out_vals, out_erased, rounds[:, 0]
@@ -281,17 +304,6 @@ def _effective_bp(p: int, bp: int) -> int:
     return max(8, min(bp - bp % 8 if bp >= 8 else 8, p8))
 
 
-def _pad_operands_tiled(H, vals, erased_f, bv, bp):
-    """Pad ONCE for a whole tiled decode: N → multiple of 128 (lanes),
-    p → multiple of ``bp`` (every streamed tile is full — ragged check-tile
-    edges become all-zero rows: never counted, never solvable, never
-    written), V → multiple of bv (payload tile)."""
-    Hp = pad_axis_to(pad_axis_to(H.astype(jnp.float32), bp, 0), 128, 1)
-    vp = pad_axis_to(pad_axis_to(vals.astype(jnp.float32), 128, -2), bv, -1)
-    ep = pad_axis_to(erased_f, 128, -2)
-    return Hp, vp, ep
-
-
 @partial(jax.jit, static_argnames=("iters", "interpret", "bp", "bv"))
 def _peel_decode_tiled_impl(H, values, erased, *, iters: int, interpret: bool,
                             bp: int = 128, bv: int = 128):
@@ -300,14 +312,11 @@ def _peel_decode_tiled_impl(H, values, erased, *, iters: int, interpret: bool,
     N, V = vals.shape
 
     bp_eff = _effective_bp(H.shape[0], bp)
-    Hp, vp, ep = _pad_operands_tiled(H, vals,
-                                     erased.astype(jnp.float32)[:, None],
-                                     bv, bp_eff)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv, bp_eff)
     out_v, out_e = decode_fused_tiled(Hp, vp, ep, iters=iters, bp=bp_eff,
-                                      bv=min(bv, vp.shape[1]),
+                                      bv=bv_,
                                       interpret=interpret)
-    out_vals = out_v[:N, :V].astype(vals.dtype)
-    out_erased = out_e[:N, 0] > 0.0
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, 0]
     return out_vals, out_erased
@@ -336,14 +345,12 @@ def _peel_decode_batch_tiled_impl(H, values, erased, *, iters: int,
     B, N, V = vals.shape
 
     bp_eff = _effective_bp(H.shape[0], bp)
-    Hp, vp, ep = _pad_operands_tiled(
-        H, vals, erased.astype(jnp.float32)[:, :, None], bv, bp_eff)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv, bp_eff)
     out_v, out_e = decode_fused_batch_tiled(Hp, vp, ep, iters=iters,
                                             bp=bp_eff,
-                                            bv=min(bv, vp.shape[2]),
+                                            bv=bv_,
                                             interpret=interpret)
-    out_vals = out_v[:, :N, :V].astype(vals.dtype)
-    out_erased = out_e[:, :N, 0] > 0.0
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, :, 0]
     return out_vals, out_erased
@@ -368,14 +375,11 @@ def _peel_decode_adaptive_tiled_impl(H, values, erased, *, max_iters: int,
     N, V = vals.shape
 
     bp_eff = _effective_bp(H.shape[0], bp)
-    Hp, vp, ep = _pad_operands_tiled(H, vals,
-                                     erased.astype(jnp.float32)[:, None],
-                                     bv, bp_eff)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv, bp_eff)
     out_v, out_e, rounds = decode_fused_adaptive_tiled(
         Hp, vp, ep, max_iters=max_iters, bp=bp_eff,
-        bv=min(bv, vp.shape[1]), interpret=interpret)
-    out_vals = out_v[:N, :V].astype(vals.dtype)
-    out_erased = out_e[:N, 0] > 0.0
+        bv=bv_, interpret=interpret)
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, 0]
     return out_vals, out_erased, rounds[0, 0]
@@ -400,13 +404,11 @@ def _peel_decode_batch_adaptive_tiled_impl(H, values, erased, budgets, *,
     B, N, V = vals.shape
 
     bp_eff = _effective_bp(H.shape[0], bp)
-    Hp, vp, ep = _pad_operands_tiled(
-        H, vals, erased.astype(jnp.float32)[:, :, None], bv, bp_eff)
+    Hp, vp, ep, bv_ = _pad_operands(H, vals, erased, bv, bp_eff)
     out_v, out_e, rounds = decode_fused_batch_adaptive_tiled(
         Hp, vp, ep, budgets.astype(jnp.int32)[:, None], bp=bp_eff,
-        bv=min(bv, vp.shape[2]), interpret=interpret)
-    out_vals = out_v[:, :N, :V].astype(vals.dtype)
-    out_erased = out_e[:, :N, 0] > 0.0
+        bv=bv_, interpret=interpret)
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, :, 0]
     return out_vals, out_erased, rounds[:, 0]
@@ -424,16 +426,9 @@ def peel_decode_batch_adaptive_tiled_pallas(H, values, erased, budgets, *,
 
 
 # ------------------------------------------------------- seeded family --
-
-
-def _pad_operands_seeded(vals, erased_f, bv):
-    """Pad ONCE for a whole seeded decode: only the PAYLOAD needs padding
-    (N → multiple of 128, V → multiple of ``bv``) — there is no H operand;
-    the kernel's generated tiles are zero on padded columns and padded
-    check rows by construction."""
-    vp = pad_axis_to(pad_axis_to(vals.astype(jnp.float32), 128, -2), bv, -1)
-    ep = pad_axis_to(erased_f, 128, -2)
-    return vp, ep
+#
+# Only the payload is padded: there is no H operand, and the generated
+# tiles are zero on padded columns and check rows by construction.
 
 
 @partial(jax.jit, static_argnames=("spec", "iters", "interpret", "bp", "bv",
@@ -446,13 +441,11 @@ def _peel_decode_seeded_impl(values, erased, *, spec, iters: int,
     N, V = vals.shape
 
     bp_eff = _effective_bp(spec.rows, bp)
-    vp, ep = _pad_operands_seeded(vals, erased.astype(jnp.float32)[:, None],
-                                  bv)
+    vp, ep, bv_ = _lanes_in(vals, erased, bv)
     out_v, out_e = decode_seeded(spec, vp, ep, iters=iters, bp=bp_eff,
-                                 bv=min(bv, vp.shape[1]), interpret=interpret,
+                                 bv=bv_, interpret=interpret,
                                  mode=mode)
-    out_vals = out_v[:N, :V].astype(vals.dtype)
-    out_erased = out_e[:N, 0] > 0.0
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, 0]
     return out_vals, out_erased
@@ -487,13 +480,11 @@ def _peel_decode_batch_seeded_impl(values, erased, *, spec, iters: int,
     B, N, V = vals.shape
 
     bp_eff = _effective_bp(spec.rows, bp)
-    vp, ep = _pad_operands_seeded(vals,
-                                  erased.astype(jnp.float32)[:, :, None], bv)
+    vp, ep, bv_ = _lanes_in(vals, erased, bv)
     out_v, out_e = decode_seeded_batch(spec, vp, ep, iters=iters, bp=bp_eff,
-                                       bv=min(bv, vp.shape[2]),
+                                       bv=bv_,
                                        interpret=interpret, mode=mode)
-    out_vals = out_v[:, :N, :V].astype(vals.dtype)
-    out_erased = out_e[:, :N, 0] > 0.0
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, :, 0]
     return out_vals, out_erased
@@ -524,13 +515,11 @@ def _peel_decode_adaptive_seeded_impl(values, erased, *, spec,
     N, V = vals.shape
 
     bp_eff = _effective_bp(spec.rows, bp)
-    vp, ep = _pad_operands_seeded(vals, erased.astype(jnp.float32)[:, None],
-                                  bv)
+    vp, ep, bv_ = _lanes_in(vals, erased, bv)
     out_v, out_e, rounds = decode_seeded_adaptive(
         spec, vp, ep, max_iters=max_iters, bp=bp_eff,
-        bv=min(bv, vp.shape[1]), interpret=interpret, mode=mode)
-    out_vals = out_v[:N, :V].astype(vals.dtype)
-    out_erased = out_e[:N, 0] > 0.0
+        bv=bv_, interpret=interpret, mode=mode)
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, 0]
     return out_vals, out_erased, rounds[0, 0]
@@ -559,13 +548,11 @@ def _peel_decode_batch_adaptive_seeded_impl(values, erased, budgets, *, spec,
     B, N, V = vals.shape
 
     bp_eff = _effective_bp(spec.rows, bp)
-    vp, ep = _pad_operands_seeded(vals,
-                                  erased.astype(jnp.float32)[:, :, None], bv)
+    vp, ep, bv_ = _lanes_in(vals, erased, bv)
     out_v, out_e, rounds = decode_seeded_batch_adaptive(
         spec, vp, ep, budgets.astype(jnp.int32)[:, None], bp=bp_eff,
-        bv=min(bv, vp.shape[2]), interpret=interpret, mode=mode)
-    out_vals = out_v[:, :N, :V].astype(vals.dtype)
-    out_erased = out_e[:, :N, 0] > 0.0
+        bv=bv_, interpret=interpret, mode=mode)
+    out_vals, out_erased = _lanes_out(out_v, out_e, N, V, vals.dtype)
     if squeeze:
         out_vals = out_vals[:, :, 0]
     return out_vals, out_erased, rounds[:, 0]

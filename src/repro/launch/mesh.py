@@ -25,18 +25,8 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
 
 
 def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free AbstractMesh, across JAX signature changes.
-
-    Older JAX (≤ 0.4.x) takes ``AbstractMesh(((name, size), ...))``; newer
-    JAX takes ``AbstractMesh(axis_sizes, axis_names)``.  Passing the new
-    calling convention to the old constructor dies with
-    ``TypeError: 'int' object is not iterable`` — this helper accepts the
-    new-style ``(shape, axes)`` pair and dispatches to whichever the
-    installed JAX understands.
-    """
+    """Device-free ``AbstractMesh(axis_sizes, axis_names)`` for AOT
+    lowering against a described mesh."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(shape, axes)  # JAX >= 0.5 signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes)

@@ -324,7 +324,8 @@ def test_seeded_worker_encode_validates_scheme():
 
 def test_distributed_grad_agg_bit_parity():
     """DistributedCodedAggregator (2-D payload worker launch) vs the
-    single-device CodedAggregator, bit for bit, several masks."""
+    single-device CodedAggregator, several masks: exact unresolved counts,
+    both sums within the peel-chain bound of the exact coded sum."""
     from repro.distributed.selfcheck import check_grad_agg_parity
     assert check_grad_agg_parity(n_shards=64, dim=17, n_workers=8,
                                  steps=4, q0=0.25) == 4
