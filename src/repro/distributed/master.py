@@ -384,13 +384,15 @@ class DistributedCodedGD:
             def master_program(idx_sh, coeff_sh, z, worker_mask, theta,
                                budget):
                 erased = topo.to_symbol_erasure(worker_mask)
-                z = eng.erase(z, erased)      # idempotent, mirrors recover()
-                vals, e2, rounds = decode_fn(idx_sh, coeff_sh, z[:, None],
-                                             erased, budget)
-                dec = DecodeResult(vals[:, 0], e2, rounds)
-                c_hat, unresolved = eng.systematic(dec)
-                g, n_unres = scheme.finish_gradient(c_hat, unresolved)
-                theta2 = scheme.projection(theta - scheme.lr * g)
+                with jax.named_scope("decode"):
+                    z = eng.erase(z, erased)  # idempotent, mirrors recover()
+                    vals, e2, rounds = decode_fn(idx_sh, coeff_sh,
+                                                 z[:, None], erased, budget)
+                    dec = DecodeResult(vals[:, 0], e2, rounds)
+                    c_hat, unresolved = eng.systematic(dec)
+                with jax.named_scope("update"):
+                    g, n_unres = scheme.finish_gradient(c_hat, unresolved)
+                    theta2 = scheme.projection(theta - scheme.lr * g)
                 return theta2, n_unres, (jnp.int32(eng_iters) if fixed_mode
                                          else rounds)
 
@@ -411,10 +413,12 @@ class DistributedCodedGD:
 
             @jax.jit
             def replay_epilogue(values, erased, theta):
-                c_hat, unresolved = eng.systematic(
-                    DecodeResult(values, erased, jnp.int32(0)))
-                g, n_unres = scheme.finish_gradient(c_hat, unresolved)
-                theta2 = scheme.projection(theta - scheme.lr * g)
+                with jax.named_scope("decode"):
+                    c_hat, unresolved = eng.systematic(
+                        DecodeResult(values, erased, jnp.int32(0)))
+                with jax.named_scope("update"):
+                    g, n_unres = scheme.finish_gradient(c_hat, unresolved)
+                    theta2 = scheme.projection(theta - scheme.lr * g)
                 return theta2, n_unres
 
             def master_program(z, worker_mask, theta, budget, H):
@@ -445,10 +449,12 @@ class DistributedCodedGD:
             def master_program(z, worker_mask, theta, budget, H):
                 del budget  # fixed-D decode; kept for a stable signature
                 erased = topo.to_symbol_erasure(worker_mask)
-                c_hat, unresolved = dataclasses.replace(eng, H=H).recover(
-                    z, erased)
-                g, n_unres = scheme.finish_gradient(c_hat, unresolved)
-                theta2 = scheme.projection(theta - scheme.lr * g)
+                with jax.named_scope("decode"):
+                    c_hat, unresolved = dataclasses.replace(
+                        eng, H=H).recover(z, erased)
+                with jax.named_scope("update"):
+                    g, n_unres = scheme.finish_gradient(c_hat, unresolved)
+                    theta2 = scheme.projection(theta - scheme.lr * g)
                 return theta2, n_unres, jnp.int32(eng.decode_iters)
         else:
             # Telemetry mode rides the engine's batched-adaptive decode at
@@ -456,11 +462,14 @@ class DistributedCodedGD:
             # budgets never recompile) and rounds_used surfaces per step.
             def master_program(z, worker_mask, theta, budget, H):
                 erased = topo.to_symbol_erasure(worker_mask)
-                dec = dataclasses.replace(eng, H=H).decode_batch(
-                    z[None], erased[None], adaptive=True, budgets=budget)
-                c_hat, unresolved = eng.systematic(dec)
-                g, n_unres = scheme.finish_gradient(c_hat[0], unresolved[0])
-                theta2 = scheme.projection(theta - scheme.lr * g)
+                with jax.named_scope("decode"):
+                    dec = dataclasses.replace(eng, H=H).decode_batch(
+                        z[None], erased[None], adaptive=True, budgets=budget)
+                    c_hat, unresolved = eng.systematic(dec)
+                with jax.named_scope("update"):
+                    g, n_unres = scheme.finish_gradient(c_hat[0],
+                                                        unresolved[0])
+                    theta2 = scheme.projection(theta - scheme.lr * g)
                 return theta2, n_unres, dec.rounds_used[0]
 
         return worker_jit, jax.jit(master_program)
@@ -480,52 +489,62 @@ class DistributedCodedGD:
         policy DECISION rather than a measurement (delay-model runs pass a
         latency-derived fraction — see :meth:`run`).  Returns
         ``(θ', n_unresolved, rounds_spent, budget)``.
+
+        Host spans (free when tracing is off): ``step/inputs`` (mask,
+        budget and broadcasts), ``worker/launch`` (the worker program's
+        enqueue) and ``master/decode``, which holds ``master/dispatch``
+        (the master program's operands and enqueue) and ``master/fetch``
+        (the blocking pull of the two scalars).
         """
-        worker_mask = jnp.asarray(worker_mask, bool)
-        if worker_mask.shape != (self.n_workers,):
-            raise ValueError(f"worker_mask must be ({self.n_workers},); "
-                             f"got {worker_mask.shape}")
-        if self.budget_mode == "telemetry":
-            if observed_fraction is None:
-                observed_fraction = float(
-                    self.topology.observed_fraction(worker_mask))
-            rate_in = self.estimator.rate   # estimate ENTERING the step
-            rate = self.estimator.observe(observed_fraction)
-            code = self.scheme.code
-            budget = decode_budget(rate, code.l, code.r,
-                                   max_rounds=self.max_rounds)
-            _record_plan_metrics("sync", rate=rate_in,
-                                 observed=observed_fraction)
-        else:
-            budget = int(self.scheme.decode_iters)
-        # broadcast θ + mask to the workers, one SPMD partial-product
-        # launch.  device_put is a no-op when the operand already carries
-        # the replicated sharding (θ handed back by a previous step), so a
-        # driver loop pays ONE broadcast per array per step, not the old
-        # replicated-put + master-put pair.
-        theta_rep = jax.device_put(theta, self._replicated)
-        mask_rep = jax.device_put(worker_mask, self._replicated)
-        budget_arr = np.asarray([budget], np.int32)
+        with _span("step/inputs", lane="master"):
+            worker_mask = jnp.asarray(worker_mask, bool)
+            if worker_mask.shape != (self.n_workers,):
+                raise ValueError(f"worker_mask must be ({self.n_workers},); "
+                                 f"got {worker_mask.shape}")
+            if self.budget_mode == "telemetry":
+                if observed_fraction is None:
+                    observed_fraction = float(
+                        self.topology.observed_fraction(worker_mask))
+                rate_in = self.estimator.rate  # estimate ENTERING the step
+                rate = self.estimator.observe(observed_fraction)
+                code = self.scheme.code
+                budget = decode_budget(rate, code.l, code.r,
+                                       max_rounds=self.max_rounds)
+                _record_plan_metrics("sync", rate=rate_in,
+                                     observed=observed_fraction)
+            else:
+                budget = int(self.scheme.decode_iters)
+            # broadcast θ + mask to the workers, one SPMD partial-product
+            # launch.  device_put is a no-op when the operand already
+            # carries the replicated sharding (θ handed back by a previous
+            # step), so a driver loop pays ONE broadcast per array per
+            # step, not the old replicated-put + master-put pair.
+            theta_rep = jax.device_put(theta, self._replicated)
+            mask_rep = jax.device_put(worker_mask, self._replicated)
+            budget_arr = np.asarray([budget], np.int32)
         with _span("worker/launch", lane="worker"):
             z = self._launch_workers(theta_rep, mask_rep)
         with _span("master/decode", lane="master", budget=budget):
-            if self.master_decode == "sharded":
-                # decode over the mesh: check tiles stay sharded; z/θ/mask
-                # are already replicated (z is the worker program's output
-                # sharding)
-                idx_sh, coeff_sh = self._sharded_tables
-                theta2, n_unres, rounds = self._master_program(
-                    idx_sh, coeff_sh, z, mask_rep, theta_rep,
-                    jax.device_put(jnp.asarray(budget_arr), self._replicated))
-            else:
-                # master-local decode + update: operands are the master
-                # device's OWN shards of the replicated worker output /
-                # broadcast (zero-copy views), plus the budget scalar which
-                # jit places alongside them.
-                theta2, n_unres, rounds = self._master_program(
-                    self._mshard(z), self._mshard(mask_rep),
-                    self._mshard(theta_rep), budget_arr, self._decode_H)
-            n_unres, rounds = int(n_unres), int(rounds)
+            with _span("master/dispatch", lane="master"):
+                if self.master_decode == "sharded":
+                    # decode over the mesh: check tiles stay sharded;
+                    # z/θ/mask are already replicated (z is the worker
+                    # program's output sharding)
+                    idx_sh, coeff_sh = self._sharded_tables
+                    theta2, n_unres, rounds = self._master_program(
+                        idx_sh, coeff_sh, z, mask_rep, theta_rep,
+                        jax.device_put(jnp.asarray(budget_arr),
+                                       self._replicated))
+                else:
+                    # master-local decode + update: operands are the master
+                    # device's OWN shards of the replicated worker output /
+                    # broadcast (zero-copy views), plus the budget scalar
+                    # which jit places alongside them.
+                    theta2, n_unres, rounds = self._master_program(
+                        self._mshard(z), self._mshard(mask_rep),
+                        self._mshard(theta_rep), budget_arr, self._decode_H)
+            with _span("master/fetch", lane="master"):
+                n_unres, rounds = int(n_unres), int(rounds)
         _record_step_metrics("sync", rounds=rounds, unresolved=n_unres,
                              budget=budget)
         return theta2, n_unres, rounds, budget
@@ -664,10 +683,12 @@ class DistributedCodedAggregator:
 
         def master_program(z, worker_mask, H):
             erased = topo.to_symbol_erasure(worker_mask)
-            recovered, unresolved = dataclasses.replace(eng, H=H).recover(
-                z, erased)
-            total = recovered.sum(axis=0) * agg.debias_scale
-            return total, unresolved.sum()
+            with jax.named_scope("decode"):
+                recovered, unresolved = dataclasses.replace(
+                    eng, H=H).recover(z, erased)
+            with jax.named_scope("sum"):
+                total = recovered.sum(axis=0) * agg.debias_scale
+                return total, unresolved.sum()
 
         self._worker_program = jax.jit(worker_program,
                                        out_shardings=self._replicated)
@@ -684,21 +705,27 @@ class DistributedCodedAggregator:
 
         One SPMD worker launch (sharded generator rows, 2-D payload), one
         master decode launch.  Returns ``(Σ_i ĝ_i (dim,), n_unresolved)``.
+        Host spans as :meth:`DistributedCodedGD.step`'s.
         """
-        partials = jnp.asarray(partials)
-        worker_mask = jnp.asarray(worker_mask, bool)
-        if worker_mask.shape != (self.n_workers,):
-            raise ValueError(f"worker_mask must be ({self.n_workers},); "
-                             f"got {worker_mask.shape}")
-        z = self._worker_program(
-            self._G_sharded,
-            jax.device_put(partials, self._replicated),
-            jax.device_put(worker_mask, self._replicated))
+        with _span("step/inputs", lane="master"):
+            partials = jnp.asarray(partials)
+            worker_mask = jnp.asarray(worker_mask, bool)
+            if worker_mask.shape != (self.n_workers,):
+                raise ValueError(f"worker_mask must be ({self.n_workers},); "
+                                 f"got {worker_mask.shape}")
+            partials_rep = jax.device_put(partials, self._replicated)
+            mask_rep = jax.device_put(worker_mask, self._replicated)
+        with _span("worker/launch", lane="worker"):
+            z = self._worker_program(self._G_sharded, partials_rep, mask_rep)
         m = self.master_device
-        total, n_unres = self._master_program(
-            jax.device_put(z, m), jax.device_put(worker_mask, m),
-            self._decode_H)
-        return total, int(n_unres)
+        with _span("master/decode", lane="master"):
+            with _span("master/dispatch", lane="master"):
+                total, n_unres = self._master_program(
+                    jax.device_put(z, m), jax.device_put(worker_mask, m),
+                    self._decode_H)
+            with _span("master/fetch", lane="master"):
+                n_unres = int(n_unres)
+        return total, n_unres
 
 
 # ------------------------------------------------- production-scale AOT step

@@ -150,6 +150,21 @@ _HIGH = jax.lax.Precision.HIGHEST
 _CONTRACT_LANES = (((1,), (1,)), ((), ()))
 
 
+def _pallas_call(name: str, kernel, **kw):
+    """``pl.pallas_call`` launched under the name scopes ``kernel/<name>``.
+
+    A profile then tells the kernel (scope ``.../kernel``) from the pads,
+    transposes and slices its callers put around it; ``name``, the public
+    kernel function's, stays the launch's op name in the profile."""
+    call = pl.pallas_call(kernel, **kw)
+
+    def launch(*operands):
+        with jax.named_scope("kernel"), jax.named_scope(name):
+            return call(*operands)
+
+    return launch
+
+
 def interpret_only(what: str, interpret: bool) -> None:
     """Refuse a compiled (TPU) launch of a kernel Mosaic cannot lower.
 
@@ -204,7 +219,8 @@ def check_pass(H: jax.Array, values: jax.Array, erased_f: jax.Array, *,
     p, N = H.shape
     V = values.shape[1]
     grid = (p // bp, V // bv)
-    return pl.pallas_call(
+    return _pallas_call(
+        "check_pass",
         _check_kernel,
         grid=grid,
         in_specs=[
@@ -403,7 +419,8 @@ def decode_fused(H: jax.Array, values: jax.Array, erased_f: jax.Array, *,
     p, N = H.shape
     V = values.shape[0]
     grid = (V // bv,)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_fused",
         functools.partial(_decode_kernel, iters=iters),
         grid=grid,
         in_specs=[
@@ -457,7 +474,8 @@ def decode_fused_batch(H: jax.Array, values: jax.Array, erased_f: jax.Array,
     p, N = H.shape
     B, V, _ = values.shape
     grid = (B, V // bv)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_fused_batch",
         functools.partial(_decode_batch_kernel, iters=iters),
         grid=grid,
         in_specs=[
@@ -512,7 +530,8 @@ def decode_fused_adaptive(H: jax.Array, values: jax.Array,
     p, N = H.shape
     V = values.shape[0]
     grid = (V // bv,)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_fused_adaptive",
         functools.partial(_decode_adaptive_kernel, max_iters=max_iters),
         grid=grid,
         in_specs=[
@@ -574,7 +593,8 @@ def decode_fused_batch_adaptive(H: jax.Array, values: jax.Array,
     p, N = H.shape
     B, V, _ = values.shape
     grid = (B, V // bv)
-    vals, erased, rounds = pl.pallas_call(
+    vals, erased, rounds = _pallas_call(
+        "decode_fused_batch_adaptive",
         _decode_batch_adaptive_kernel,
         grid=grid,
         in_specs=[
@@ -654,7 +674,8 @@ def decode_fused_tiled(H: jax.Array, values: jax.Array, erased_f: jax.Array,
     V = values.shape[0]
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (V // bv,)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_fused_tiled",
         functools.partial(_decode_tiled_kernel, iters=iters, bp=bp),
         grid=grid,
         in_specs=[
@@ -705,7 +726,8 @@ def decode_fused_batch_tiled(H: jax.Array, values: jax.Array,
     B, V, _ = values.shape
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (B, V // bv)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_fused_batch_tiled",
         functools.partial(_decode_batch_tiled_kernel, iters=iters, bp=bp),
         grid=grid,
         in_specs=[
@@ -758,7 +780,8 @@ def decode_fused_adaptive_tiled(H: jax.Array, values: jax.Array,
     V = values.shape[0]
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (V // bv,)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_fused_adaptive_tiled",
         functools.partial(_decode_adaptive_tiled_kernel, max_iters=max_iters,
                           bp=bp),
         grid=grid,
@@ -814,7 +837,8 @@ def decode_fused_batch_adaptive_tiled(H: jax.Array, values: jax.Array,
     B, V, _ = values.shape
     _check_tiled_operands(p, N, V, bp, bv)
     grid = (B, V // bv)
-    vals, erased, rounds = pl.pallas_call(
+    vals, erased, rounds = _pallas_call(
+        "decode_fused_batch_adaptive_tiled",
         functools.partial(_decode_batch_adaptive_tiled_kernel, bp=bp),
         grid=grid,
         in_specs=[
@@ -1130,7 +1154,8 @@ def decode_seeded(spec, values: jax.Array, erased_f: jax.Array, *,
     V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (V // bv,)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_seeded",
         functools.partial(_decode_seeded_kernel, spec=spec, iters=iters,
                           bp=bp, mode=mode),
         grid=grid,
@@ -1181,7 +1206,8 @@ def decode_seeded_batch(spec, values: jax.Array, erased_f: jax.Array, *,
     B, V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (B, V // bv)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_seeded_batch",
         functools.partial(_decode_seeded_batch_kernel, spec=spec,
                           iters=iters, bp=bp, mode=mode),
         grid=grid,
@@ -1231,7 +1257,8 @@ def decode_seeded_adaptive(spec, values: jax.Array, erased_f: jax.Array, *,
     V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (V // bv,)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_seeded_adaptive",
         functools.partial(_decode_seeded_adaptive_kernel, spec=spec,
                           max_iters=max_iters, bp=bp, mode=mode),
         grid=grid,
@@ -1287,7 +1314,8 @@ def decode_seeded_batch_adaptive(spec, values: jax.Array,
     B, V, N = values.shape
     _check_seeded_operands(spec, N, V, bp, bv)
     grid = (B, V // bv)
-    vals, erased, rounds = pl.pallas_call(
+    vals, erased, rounds = _pallas_call(
+        "decode_seeded_batch_adaptive",
         functools.partial(_decode_seeded_batch_adaptive_kernel, spec=spec,
                           bp=bp, mode=mode),
         grid=grid,
@@ -1391,7 +1419,8 @@ def encode_seeded_fused(st, y: jax.Array, row0: jax.Array, *, n_out: int,
             f"n_out % bo == 0, bo % 8 == 0; got K_pad={K_pad} "
             f"(cols={st.cols}), V={V} bv={bv}, n_out={n_out} bo={bo}")
     grid = (n_out // bo, V // bv)
-    return pl.pallas_call(
+    return _pallas_call(
+        "encode_seeded_fused",
         functools.partial(_encode_seeded_kernel, st=st, bo=bo),
         grid=grid,
         in_specs=[
@@ -1505,7 +1534,8 @@ def decode_replay(nidx: jax.Array, w: jax.Array, coeff: jax.Array,
     n_pad, V = values.shape
     S, r_max = nidx.shape
     grid = (V // bv,)
-    return pl.pallas_call(
+    return _pallas_call(
+        "decode_replay",
         functools.partial(_replay_kernel, rounds=rounds, maxseg=maxseg,
                           n_real=n_real),
         grid=grid,

@@ -26,6 +26,15 @@ Activation is process-local: :func:`enable` installs a registry,
 (restoring whatever was active before).  Histograms use fixed bin edges
 fixed at creation (numpy ``searchsorted`` buckets); the shared edge
 constants below keep the same quantity comparable across layers.
+
+Compile time: the first time a registry is enabled, one JAX monitoring
+duration listener is registered (once per process, and only where jax
+imports).  While a registry is active it adds JAX's compile events to
+``jax.compile_seconds{stage=trace|lower|backend}`` (a backend compile
+includes a persistent-cache read) and counts backend compiles in
+``jax.compiles``; with none active it records nothing.  Nested events
+(an inner jit traced inside an outer one) count each second once, under
+the innermost stage.
 """
 from __future__ import annotations
 
@@ -257,6 +266,51 @@ class MetricsRegistry:
         return path
 
 
+# ------------------------------------------------------- JAX compile events
+
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_listening = False
+# (start, end) perf_counter intervals of the compile events already
+# counted, disjoint and sorted: a nested event adds only its uncovered part
+_compile_cover: list = []
+
+
+def _on_jax_duration(event: str, duration: float, **_) -> None:
+    stage = COMPILE_STAGES.get(event)
+    reg = _active
+    if stage is None or reg is None:
+        return
+    end = time.perf_counter()
+    start = end - duration
+    i = len(_compile_cover)
+    while i and _compile_cover[i - 1][1] > start:
+        i -= 1
+    inner = _compile_cover[i:]
+    covered = sum(e - max(s, start) for s, e in inner)
+    _compile_cover[i:] = [(min([start] + [s for s, _ in inner]), end)]
+    reg.counter("jax.compile_seconds", stage=stage).inc(
+        max(duration - covered, 0.0))
+    if stage == "backend":
+        reg.counter("jax.compiles").inc()
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_on_jax_duration` with JAX, once per process."""
+    global _listening
+    if _listening:
+        return
+    try:
+        from jax import monitoring
+    except ImportError:
+        return
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _listening = True
+
+
 # ----------------------------------------------------- process-local switch
 
 _active: MetricsRegistry | None = None
@@ -266,6 +320,7 @@ def enable(registry: MetricsRegistry | None = None) -> MetricsRegistry:
     """Install ``registry`` (or a fresh one) as the process-local sink that
     every instrumentation site records into.  Returns it."""
     global _active
+    _listen_for_compiles()
     _active = registry if registry is not None else MetricsRegistry()
     return _active
 
@@ -287,6 +342,7 @@ def active() -> MetricsRegistry | None:
 def recording(registry: MetricsRegistry | None = None):
     """Scope a registry around a block, restoring the previous one after."""
     global _active
+    _listen_for_compiles()
     prev = _active
     reg = registry if registry is not None else MetricsRegistry()
     _active = reg

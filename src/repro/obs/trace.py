@@ -24,9 +24,14 @@ also active, each finished span feeds ``trace.span_seconds`` /
 ``trace.span_count{name=...}`` counters so :mod:`repro.obs.report` can
 render a per-phase time breakdown from the JSONL alone.
 
-``Tracer(jax_annotations=True)`` additionally wraps synchronous spans in
-``jax.profiler.TraceAnnotation`` so they nest inside real XLA profiler
-traces on TPU; jax is imported lazily and only in that mode.
+Which spans reach a device profile: ``Tracer(jax_annotations=True)``
+wraps each synchronous :func:`span` in ``jax.profiler.TraceAnnotation``,
+so it lands on the profiler's host plane on the same clock as the device
+ops (``bench/trace_reduce.py`` labels idle device time with it).
+:meth:`Tracer.complete` stamps (the pipelined driver's async spans) do
+not: they exist only in this tracer's own export, because the profiler's
+host events are offsets from the start of its trace, not this module's
+``perf_counter`` clock.  jax is imported lazily and only in that mode.
 """
 from __future__ import annotations
 
@@ -107,13 +112,6 @@ class Tracer:
             ev["args"] = {k: _jsonable(v) for k, v in args.items()}
         self.events.append(ev)
         self._feed_metrics(name, dur_us)
-
-    def instant(self, name: str, lane: str = "main", **args) -> None:
-        ev = {"ph": "i", "name": name, "pid": self.pid,
-              "tid": self.lane(lane), "ts": now_us(), "s": "t"}
-        if args:
-            ev["args"] = {k: _jsonable(v) for k, v in args.items()}
-        self.events.append(ev)
 
     def export(self, path) -> Path:
         path = Path(path)

@@ -105,6 +105,80 @@ def test_export_jsonl_round_trips(tmp_path):
         json.loads(line)
 
 
+# ------------------------------------------------------ compile counter
+
+
+def _fresh_program():
+    """A jitted function JAX has never seen: its first call compiles."""
+    import jax
+
+    def program(x):
+        return x * 3.0 + 1.0
+
+    return jax.jit(program)
+
+
+def test_compile_under_recording_is_counted():
+    import jax.numpy as jnp
+
+    x = jnp.ones(4)
+    with metrics.recording() as reg:
+        _fresh_program()(x).block_until_ready()
+    assert reg.counter("jax.compiles").value >= 1
+    stages = {m["labels"]["stage"]: m["value"]
+              for m in reg.snapshot().values()
+              if m["name"] == "jax.compile_seconds"}
+    assert {"trace", "lower", "backend"} <= set(stages)
+    assert all(v >= 0.0 for v in stages.values())
+    assert stages["backend"] > 0.0
+
+
+def test_compile_with_no_registry_adds_nothing():
+    import jax.numpy as jnp
+
+    x = jnp.ones(4)
+    with metrics.recording() as reg:     # the listener is registered
+        pass
+    idle = metrics.MetricsRegistry()
+    assert metrics.active() is None
+    _fresh_program()(x).block_until_ready()
+    assert len(reg) == 0 and len(idle) == 0
+
+
+def test_compile_listener_is_registered_once(monkeypatch):
+    from jax import monitoring
+
+    added = []
+    monkeypatch.setattr(monitoring, "register_event_duration_secs_listener",
+                        added.append)
+    monkeypatch.setattr(metrics, "_listening", False)
+    try:
+        for _ in range(3):
+            metrics.enable()
+        with metrics.recording():
+            pass
+    finally:
+        metrics.disable()
+    assert added == [metrics._on_jax_duration]
+
+
+def test_nested_compile_events_count_each_second_once(monkeypatch):
+    monkeypatch.setattr(metrics, "_compile_cover", [])
+    with metrics.recording() as reg:
+        # an inner trace of 0.2 s ends inside an outer one of 0.5 s
+        metrics._on_jax_duration(
+            "/jax/core/compile/jaxpr_trace_duration", 0.2)
+        metrics._on_jax_duration(
+            "/jax/core/compile/jaxpr_trace_duration", 0.5)
+        metrics._on_jax_duration(
+            "/jax/core/compile/backend_compile_duration", 1e-9)
+        metrics._on_jax_duration("/jax/other/event", 7.0)
+    assert reg.counter("jax.compile_seconds",
+                       stage="trace").value == pytest.approx(0.5, abs=1e-3)
+    assert reg.counter("jax.compiles").value == 1
+    assert reg.get("jax.compile_seconds", stage="lower") is None
+
+
 # --------------------------------------------------------------- tracer
 
 

@@ -128,6 +128,19 @@ def test_check_pass(one_chip):
              interpret=False)
 
 
+def test_kernel_launch_is_scoped_and_keeps_its_name(one_chip):
+    """The launch sits in the ``kernel`` name scope, and its op is still
+    named after the kernel function, as the profile shows it."""
+    p, n = _resident_shape()
+    S = lambda *a: _spec(one_chip, *a)
+    hlo = _compile(ops._peel_decode_impl, S((p, n)), S((n,)),
+                   S((n,), jnp.bool_), iters=10, interpret=False).as_text()
+    call = next(line for line in hlo.splitlines()
+                if "tpu_custom_call" in line)
+    assert call.lstrip().startswith("%decode_fused.")
+    assert "/kernel/decode_fused/pallas_call" in call
+
+
 # Kernels whose in-kernel gathers Mosaic rejects: a compiled launch must
 # fail up front with a clear error, never deep in the compiler (or fall
 # back to another backend unannounced).  No topology needed.
