@@ -43,6 +43,21 @@ backend    what runs
            while_loop PER SLOT with a traced per-slot round budget) are
            each ONE launch.  Runs in interpret mode off-TPU (correct but
            not fast on CPU).
+
+           Layout (:func:`decode_layout`, counted as
+           ``decoder.layout_total{layout}``): the kernels carry the
+           payload LANE-MAJOR, ``(V, N)`` with the code on lanes, which
+           suits a narrow payload.  A fixed-D decode of a wide payload
+           (``V >= 512`` lanes, e.g. a gradient of width dim under the
+           (40, 20) code) runs SYMBOL-MAJOR
+           instead: the payload stays ``(N, V)`` as the workers produce
+           it, the erasure trajectory is solved once per call on H and the
+           mask, and one pass over lane tiles (:func:`pick_tile_lanes`)
+           copies the known rows and computes only the resolved ones
+           (:func:`repro.kernels.ldpc_peel.peel_decode_symbol_major_pallas`).
+           Same trajectory; erased coordinates left unresolved come back
+           as 0.  The crossover, near 340 lanes for N = 40 and N = 896,
+           was measured on one TPU v5e (PERF.md, the V sweep).
 "pallas_tiled"
            the same four one-launch contracts with ``H`` STREAMED over
            CHECK tiles from HBM (``bp`` rows at a time, double-buffered
@@ -207,6 +222,11 @@ _AUTO_SPARSE_MIN_N = 256
 # the ~16 MiB/core, leaving headroom for the pipeline's own double
 # buffering.  Overridable per call/engine via ``vmem_budget_bytes``.
 _DEFAULT_VMEM_BUDGET_BYTES = 8 * 2**20
+# Narrowest payload the "pallas" fixed-D decode carries symbol-major
+# (decode_layout); below it the lane-major resident kernel stays.  On one
+# TPU v5e the two cross near 340 lanes for N = 40 and for N = 896 alike
+# (PERF.md, the V sweep): symbol-major's trajectory solve is a fixed cost.
+_SYMBOL_MAJOR_MIN_V = 512
 
 
 def _kernel_shape(code) -> tuple[int, int]:
@@ -262,6 +282,31 @@ def pick_tile_bp(code, *, vmem_budget_bytes: int | None = None) -> int:
     bp = (budget // 2) // (2 * Npad * 4)
     bp -= bp % 8
     return int(max(8, min(bp, p + (-p) % 8)))
+
+
+def decode_layout(backend: str, V: int) -> str:
+    """How a resolved fixed-D single-pattern decode lays out its payload:
+    "symbol_major" where ``backend == "pallas"`` and the ``(N, V)`` payload
+    is at least ``_SYMBOL_MAJOR_MIN_V`` lanes wide, else "lane_major"
+    (every other Pallas kernel, and the XLA backends, which take the
+    payload as given).  The measured crossover was the same for N = 40
+    and N = 896."""
+    if backend == "pallas" and V >= _SYMBOL_MAJOR_MIN_V:
+        return "symbol_major"
+    return "lane_major"
+
+
+def pick_tile_lanes(code, V: int, *,
+                    vmem_budget_bytes: int | None = None) -> int:
+    """Lane-tile width of the symbol-major decode: the widest multiple of
+    512 lanes (at least 512) whose double-buffered ``(N, bv)`` payload and
+    output blocks fit the VMEM budget, and no wider than ``V`` rounded up
+    to 128 lanes."""
+    budget = vmem_budget_bytes or _DEFAULT_VMEM_BUDGET_BYTES
+    _, N = _kernel_shape(code)
+    bv = budget // (4 * (N + (-N) % 8) * 4)
+    bv = max(512, bv - bv % 512)
+    return int(min(bv, V + (-V) % 128))
 
 
 class DecodeResult(NamedTuple):
@@ -336,6 +381,17 @@ def resolve_backend(backend: str, code, *, adaptive: bool = False,
         reg.counter("decoder.resolve_total",
                     requested=requested, resolved=backend).inc()
     return backend
+
+
+def _count_layout(backend: str, V: int) -> str:
+    """:func:`decode_layout`, counted as ``decoder.layout_total{layout}``
+    in the active registry: one increment per trace of a fixed-D Pallas
+    decode, like ``decoder.resolve_total``."""
+    layout = decode_layout(backend, V)
+    reg = _obs_metrics.active()
+    if reg is not None:
+        reg.counter("decoder.layout_total", layout=layout).inc()
+    return layout
 
 
 # --------------------------------------------------------------- dense round
@@ -939,13 +995,21 @@ def peel_decode(
         idx, coeff = _tables(code)
         v, e = peel_fixed_sparse(idx, coeff, v, e, iters)
     elif backend == "pallas":
-        from repro.kernels.ldpc_peel import peel_decode_pallas
+        from repro.kernels.ldpc_peel import (peel_decode_pallas,
+                                             peel_decode_symbol_major_pallas)
 
         H = _dense_h(code, H, v.dtype)
-        v, e = peel_decode_pallas(H, v, e, iters)
+        if _count_layout(backend, v.shape[1]) == "symbol_major":
+            v, e = peel_decode_symbol_major_pallas(
+                H, v, e, iters, max_degree=code.check_idx.shape[1],
+                bv=pick_tile_lanes(code, v.shape[1],
+                                   vmem_budget_bytes=vmem_budget_bytes))
+        else:
+            v, e = peel_decode_pallas(H, v, e, iters)
     elif backend == "pallas_tiled":
         from repro.kernels.ldpc_peel import peel_decode_tiled_pallas
 
+        _count_layout(backend, v.shape[1])
         bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
         H = _dense_h(code, H, v.dtype)
         v, e = peel_decode_tiled_pallas(H, v, e, iters, bp=bp_, bv=bv_)
@@ -954,6 +1018,7 @@ def peel_decode(
 
         bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
         mode = _resolve_seeded_mode(seeded_mode, code, v.shape[1], bp_)
+        _count_layout(backend, v.shape[1])
         v, e = peel_decode_seeded_pallas(_seeded_spec(code), v, e, iters,
                                          bp=bp_, bv=bv_, mode=mode)
     else:

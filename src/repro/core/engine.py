@@ -56,6 +56,7 @@ import numpy as np
 from repro.core.decoder import (
     SEEDED_MODES,
     DecodeResult,
+    decode_layout,
     peel_decode,
     peel_decode_adaptive,
     peel_decode_batch,
@@ -312,9 +313,20 @@ class CodedComputeEngine:
     def recover(self, symbols: jax.Array, mask: jax.Array
                 ) -> tuple[jax.Array, jax.Array]:
         """erase → decode → epilogue for one pattern: returns the
-        zero-filled systematic (K, ...) values and the (K,) unresolved mask."""
-        dec = self.decode(self.erase(symbols, mask), mask)
-        return self.systematic(dec)
+        zero-filled systematic (K, ...) values and the (K,) unresolved mask.
+        The symbol-major decode zeroes the erased rows itself and reads no
+        value there, so on that layout the erase is left out."""
+        if not self._decode_erases(symbols):
+            symbols = self.erase(symbols, mask)
+        return self.systematic(self.decode(symbols, mask))
+
+    def _decode_erases(self, symbols: jax.Array) -> bool:
+        if self.adaptive or symbols.ndim != 2:
+            return False
+        backend = resolve_backend(self.backend, self.code,
+                                  vmem_budget_bytes=self.vmem_budget_bytes)
+        return (decode_layout(backend, symbols.shape[1])
+                == "symbol_major")
 
     def recover_batch(self, symbols: jax.Array, mask: jax.Array, *,
                       adaptive: bool | None = None,

@@ -24,6 +24,11 @@ regenerated in-register from the seed inside the flooding round
 (``seeded_h_tile``), so H costs zero bytes of HBM storage and traffic —
 same erasure trajectories, values bit-identical to the tiled path.
 
+``peel_decode_symbol_major_pallas`` is the fixed-D decode of a payload
+wide against the code: the trajectory is solved once per call on H and
+the mask, and one pass over lane tiles of the untransposed ``(N, V)``
+payload touches only the rows it resolves.
+
 ``peel_decode_replay_pallas`` drops the round structure entirely: it takes
 a precompiled ``repro.core.PeelSchedule`` (value-independent elimination
 order) and replays the resolved edges as one fused gather/FMA launch —
@@ -45,6 +50,7 @@ from repro.kernels.ldpc_peel.kernel import (
     decode_seeded_adaptive,
     decode_seeded_batch,
     decode_seeded_batch_adaptive,
+    decode_symbol_major,
     seeded_h_tile,
 )
 from repro.kernels.ldpc_peel.ops import (
@@ -60,6 +66,7 @@ from repro.kernels.ldpc_peel.ops import (
     peel_decode_pallas,
     peel_decode_replay_pallas,
     peel_decode_seeded_pallas,
+    peel_decode_symbol_major_pallas,
     peel_decode_tiled_pallas,
     peel_round_pallas,
 )
@@ -73,13 +80,13 @@ __all__ = ["peel_round_pallas", "peel_decode_pallas",
            "peel_decode_seeded_pallas", "peel_decode_batch_seeded_pallas",
            "peel_decode_adaptive_seeded_pallas",
            "peel_decode_batch_adaptive_seeded_pallas",
-           "peel_decode_replay_pallas",
+           "peel_decode_replay_pallas", "peel_decode_symbol_major_pallas",
            "check_pass", "decode_fused", "decode_fused_batch",
            "decode_fused_adaptive", "decode_fused_batch_adaptive",
            "decode_fused_tiled", "decode_fused_batch_tiled",
            "decode_fused_adaptive_tiled",
            "decode_fused_batch_adaptive_tiled",
-           "decode_replay",
+           "decode_replay", "decode_symbol_major",
            "decode_seeded", "decode_seeded_batch",
            "decode_seeded_adaptive", "decode_seeded_batch_adaptive",
            "seeded_h_tile"]

@@ -28,6 +28,19 @@ see :func:`_check_tile_proposal` / :func:`_resident_round` /
   one core's VMEM; the VMEM cost is ``2·bp·N`` stream slots plus the value
   carry, independent of ``p``.
 
+* :func:`decode_symbol_major` — a solved fixed-``D`` trajectory applied
+  to a wide payload in its own ``(N, V)`` layout (code axis on sublanes,
+  payload on lanes): no rounds, no H operand, no transpose and no
+  128-lane padding of the code axis.  ops.py solves the trajectory once
+  per call on H and the mask; the kernel streams ``(N, bv)`` lane tiles,
+  copies each, zeroes its erased rows and sets each resolved row to its
+  winning check's weighted sum of the other rows, in round order.  The
+  schedule rides in SMEM as scalar prefetch and rows are addressed at
+  dynamic sublane offsets, which Mosaic lowers (the replay kernel's value
+  gathers it does not).  ``core/decoder.decode_layout`` takes it for
+  ``V >= 512`` lanes (measured crossover near 340 lanes on one v5e);
+  below that the lane-major :func:`decode_fused` stays.
+
 The in-kernel "scatter" is expressed MXU-style: the per-check resolution
 one-hot ``(bp, N)`` becomes the right operand of a matmul that moves each
 resolved coordinate's new value into place — TPUs have no efficient
@@ -138,7 +151,8 @@ __all__ = ["check_pass", "decode_fused", "decode_fused_batch",
            "decode_fused_adaptive_tiled", "decode_fused_batch_adaptive_tiled",
            "decode_seeded", "decode_seeded_batch", "decode_seeded_adaptive",
            "decode_seeded_batch_adaptive", "seeded_h_tile",
-           "encode_seeded_fused", "decode_replay", "detect_interpret",
+           "encode_seeded_fused", "decode_replay", "decode_symbol_major",
+           "detect_interpret",
            "interpret_only"]
 
 SEEDED_MODES = ("dense_tile", "gather")
@@ -440,6 +454,89 @@ def decode_fused(H: jax.Array, values: jax.Array, erased_f: jax.Array, *,
         ],
         interpret=interpret,
     )(H, values, erased_f)
+
+
+# ---------------------------------------------------- symbol-major decode --
+
+
+def _symbol_major_kernel(tgt_ref, nbr_ref, w_ref, scale_ref, cnt_ref,
+                         vals_ref, out_ref, *, slots: int, chunk: int):
+    """One ``(N, bv)`` lane tile: copy it, zero the erased rows, then
+    resolve the scheduled rows in round order.
+
+    Rows are addressed with dynamic sublane indices read from SMEM; the
+    lane axis is walked in ``chunk``-wide strips so each row's running
+    sum stays in registers."""
+    n_erased, n_res = cnt_ref[0], cnt_ref[1]
+    zero = jnp.zeros((1, chunk), jnp.float32)
+
+    def strip(cols):
+        out_ref[:, cols] = vals_ref[:, cols]
+
+        def erase(i, c):
+            out_ref[pl.ds(tgt_ref[i], 1), cols] = zero
+            return c
+
+        def resolve(i, c):
+            b = i * slots
+            acc = w_ref[b] * out_ref[pl.ds(nbr_ref[b], 1), cols]
+            for s in range(1, slots):
+                acc = acc + w_ref[b + s] * out_ref[pl.ds(nbr_ref[b + s], 1),
+                                                   cols]
+            out_ref[pl.ds(tgt_ref[i], 1), cols] = acc * scale_ref[i]
+            return c
+
+        jax.lax.fori_loop(0, n_erased, erase, 0)
+        jax.lax.fori_loop(0, n_res, resolve, 0)
+
+    @pl.loop(0, out_ref.shape[1] // chunk)
+    def _(k):
+        strip(pl.ds(pl.multiple_of(k * chunk, chunk), chunk))
+
+
+@functools.partial(jax.jit, static_argnames=("bv", "chunk", "interpret"))
+def decode_symbol_major(tgt: jax.Array, nbr: jax.Array, w: jax.Array,
+                        scale: jax.Array, counts: jax.Array,
+                        values: jax.Array, *, bv: int, chunk: int,
+                        interpret: bool | None = None) -> jax.Array:
+    """Apply a solved peel trajectory to a wide ``(N, V)`` payload.
+
+    The payload stays as the workers produce it: code axis on sublanes,
+    payload on lanes.  The schedule (built by ops.py from H and the mask
+    alone) rides in SMEM as scalar prefetch:
+
+    * ``tgt (N,) i32`` — the erased coordinates first, those resolved in
+      round order ahead of those left unresolved, then the known ones;
+    * ``counts (2,) i32`` — how many are erased, how many resolved;
+    * ``nbr (N·slots,) i32`` / ``w (N·slots,) f32`` — for the ``i``-th
+      resolved coordinate, the other neighbours of its winning check and
+      their weights (padding slots point at the target itself with
+      weight 0: that row is still zero when it is read);
+    * ``scale (N,) f32`` — ``-1 / H[check, target]``.
+
+    Each lane tile is copied, its erased rows zeroed, and each resolved
+    row set to ``scale · Σ w·row`` in round order.  A coordinate resolved
+    in round ``t`` reads only rows known at the start of ``t``, so the
+    sequential updates equal the flooding rounds.  Erased rows the
+    schedule leaves unresolved come back as 0, so the input's values on
+    erased rows are never used.  ``bv`` is a multiple of ``chunk``, itself
+    a multiple of 128 lanes; the last tile may be ragged.
+    """
+    interpret = detect_interpret(interpret)
+    N, V = values.shape
+    slots = nbr.shape[0] // N
+    return _pallas_call(
+        "decode_symbol_major",
+        functools.partial(_symbol_major_kernel, slots=slots, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(pl.cdiv(V, bv),),
+            in_specs=[pl.BlockSpec((N, bv), lambda j, *_: (0, j))],
+            out_specs=pl.BlockSpec((N, bv), lambda j, *_: (0, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((N, V), jnp.float32),
+        interpret=interpret,
+    )(tgt, nbr, w, scale, counts, values)
 
 
 # --------------------------------------------------- batched fused decode --

@@ -6,6 +6,12 @@
   fixed-``D`` decode inside a single ``pallas_call`` (H resident in VMEM
   across rounds, scatter epilogue fused in-kernel), unpad once.  This is
   what ``repro.core.decoder.peel_decode(..., backend="pallas")`` calls.
+* :func:`peel_decode_symbol_major_pallas` — the fixed-``D`` decode of a
+  wide ``(N, V)`` payload without a transpose: :func:`peel_trajectory`
+  solves the erasure trajectory once on H and the mask (a few XLA ops on
+  the ``(p, N)`` H), then :func:`decode_symbol_major` makes one pass over
+  lane tiles of the payload.  ``peel_decode(..., backend="pallas")``
+  calls it where ``core/decoder.decode_layout`` says "symbol_major".
 * :func:`peel_decode_batch_pallas` — ``B`` independent erasure patterns in
   one launch (grid over the batch, H resident and shared); the kernel side
   of ``CodedComputeEngine.decode_batch``.
@@ -56,6 +62,7 @@ recompiling per shard.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -78,6 +85,7 @@ from repro.kernels.ldpc_peel.kernel import (
     decode_seeded_adaptive,
     decode_seeded_batch,
     decode_seeded_batch_adaptive,
+    decode_symbol_major,
     detect_interpret,
     encode_seeded_fused,
 )
@@ -91,7 +99,8 @@ __all__ = ["peel_round_pallas", "peel_decode_pallas",
            "peel_decode_seeded_pallas", "peel_decode_batch_seeded_pallas",
            "peel_decode_adaptive_seeded_pallas",
            "peel_decode_batch_adaptive_seeded_pallas",
-           "encode_seeded_fused_pallas", "peel_decode_replay_pallas"]
+           "encode_seeded_fused_pallas", "peel_decode_replay_pallas",
+           "peel_decode_symbol_major_pallas", "peel_trajectory"]
 
 
 @partial(jax.jit, static_argnames=("interpret", "bp", "bv"))
@@ -198,6 +207,94 @@ def peel_decode_pallas(H, values, erased, iters: int, *,
     """
     return _peel_decode_impl(H, values, erased, iters=int(iters),
                              interpret=detect_interpret(interpret), bv=bv)
+
+
+def peel_trajectory(H, erased, iters: int, slots: int):
+    """Solve a fixed-``iters`` flooding decode on the mask alone.
+
+    The same rounds as :func:`repro.kernels.ldpc_peel.kernel
+    ._check_tile_proposal`: a check with exactly one erased neighbour is
+    solvable, it resolves that neighbour, and the lowest such check wins a
+    coordinate.  None of it reads a payload value, so it runs once per
+    decode on the ``(p, N)`` H, not once per payload tile.
+
+    Returns the operands of :func:`decode_symbol_major` — ``tgt (N,)``,
+    ``nbr (N·slots,)``, ``w (N·slots,)``, ``scale (N,)``, ``counts (2,)``
+    — and the erasure mask left after ``iters`` rounds.  ``slots`` is at
+    least the largest check degree less one.
+    """
+    p, N = H.shape
+    Hb = H != 0.0
+    col = jnp.arange(N, dtype=jnp.int32)
+    row = jnp.arange(p, dtype=jnp.int32)[:, None]
+
+    def round_(t, carry):
+        e, when, chk = carry
+        emask = Hb & e
+        solvable = jnp.sum(emask, axis=1, keepdims=True) == 1
+        pos = jnp.max(jnp.where(emask, col, -1), axis=1, keepdims=True)
+        onehot = (col == pos) & solvable
+        winner = jnp.min(jnp.where(onehot, row, p), axis=0)
+        resolved = winner < p
+        return (e & ~resolved, jnp.where(resolved, t, when),
+                jnp.where(resolved, winner, chk))
+
+    e0 = jnp.asarray(erased, bool)
+    e, when, chk = jax.lax.fori_loop(
+        0, iters, round_,
+        (e0, jnp.full((N,), iters, jnp.int32), jnp.zeros((N,), jnp.int32)),
+        unroll=True)
+    # resolved in round order, then unresolved erased, then known
+    key = jnp.where(e0, when, iters + 1)
+    tgt = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.stack([jnp.sum(e0), jnp.sum(when < iters)]).astype(jnp.int32)
+
+    h_row = H[chk[tgt]]                                        # (N, N)
+    other = (h_row != 0.0) & (col != tgt[:, None])
+    _, nbr = jax.lax.top_k(jnp.where(other, -col, -N - 1), slots)
+    valid = jnp.take_along_axis(other, nbr, axis=1)
+    w = jnp.where(valid, jnp.take_along_axis(h_row, nbr, axis=1), 0.0)
+    nbr = jnp.where(valid, nbr, tgt[:, None]).astype(jnp.int32)
+    coeff = jnp.take_along_axis(h_row, tgt[:, None], axis=1)[:, 0]
+    scale = -1.0 / jnp.where(coeff == 0.0, 1.0, coeff)
+    return (tgt, nbr.reshape(-1), w.reshape(-1).astype(jnp.float32),
+            scale.astype(jnp.float32), counts), e
+
+
+@partial(jax.jit, static_argnames=("iters", "slots", "bv", "chunk",
+                                   "interpret"))
+def _peel_decode_symbol_major_impl(H, values, erased, *, iters: int,
+                                   slots: int, bv: int, chunk: int,
+                                   interpret: bool):
+    squeeze = values.ndim == 1
+    vals = values[:, None] if squeeze else values
+    sched, out_e = peel_trajectory(H.astype(jnp.float32), erased, iters,
+                                   slots)
+    out_v = decode_symbol_major(*sched, vals.astype(jnp.float32), bv=bv,
+                                chunk=chunk, interpret=interpret)
+    out_v = out_v.astype(vals.dtype)
+    return (out_v[:, 0] if squeeze else out_v), out_e
+
+
+def peel_decode_symbol_major_pallas(H, values, erased, iters: int, *,
+                                    max_degree: int, bv: int,
+                                    interpret: bool | None = None):
+    """Fixed-D decode of a wide payload in its own ``(N, V)`` layout.
+
+    The erasure trajectory is solved once on H and the mask
+    (:func:`peel_trajectory`), then one pass over ``bv``-lane tiles of
+    the payload copies the known rows and computes only the resolved ones
+    (:func:`decode_symbol_major`).  ``max_degree`` is the code's largest
+    check degree; ``bv`` a multiple of 128 lanes
+    (``core/decoder.pick_tile_lanes``).  Same trajectory and unresolved mask as
+    :func:`peel_decode_pallas`, values to f32 summation order; erased
+    coordinates left unresolved come back as 0 whatever the input holds
+    there.
+    """
+    return _peel_decode_symbol_major_impl(
+        H, values, erased, iters=int(iters), slots=max(int(max_degree) - 1, 1),
+        bv=int(bv), chunk=128 * math.gcd(int(bv) // 128, 4),
+        interpret=detect_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("iters", "interpret", "bv"))

@@ -90,6 +90,8 @@ def summarize(meta: dict, entries: list[dict]) -> str:
                 f"vmem_est={info.get('vmem_bytes_estimate')}")
         for e in by.get("decoder.resolve_total", []):
             add(f"  resolve[{_label(e)}]: {int(e['value'])}")
+        for e in by.get("decoder.layout_total", []):
+            add(f"  layout[{_label(e)}]: {int(e['value'])}")
 
     strag = by.get("distributed.straggler.tracking_error", [])
     if strag or "distributed.straggler.observed" in by:

@@ -234,6 +234,118 @@ def test_batched_and_adaptive_fused_decodes_are_one_kernel_launch():
     assert str(jaxpr).count("pallas_call") == 1
 
 
+# ------------------------------------------- symbol-major wide payloads --
+
+def _gradagg_mask(kind: str, N: int = 40, K: int = 20) -> np.ndarray:
+    """Erasure masks on the (40, 20) code of the gradient-bucket cell."""
+    m = np.zeros(N, bool)
+    if kind == "one_systematic":
+        m[3] = True
+    elif kind == "parity_heavy":
+        m[[1, 22, 25, 27, 30, 31, 33, 36, 38]] = True
+    elif kind == "unresolvable":
+        m[:K] = True                      # every systematic symbol
+    elif kind == "all":
+        m[:] = True
+    return m
+
+
+@pytest.mark.parametrize("kind", ["none", "one_systematic", "parity_heavy",
+                                  "unresolvable", "all"])
+@pytest.mark.parametrize("D", [0, 1, 10])
+@pytest.mark.parametrize("V", [128, 1000, 4096])
+def test_symbol_major_matches_sparse_and_lane_major(V, D, kind):
+    """The symbol-major decode (through ``backend="pallas"`` where the
+    payload is past the crossover, directly at 128 lanes): the same final
+    mask as sparse and the lane-major kernel, bit for bit; values to the
+    f32 tolerance of the tiled tests; erased coordinates it leaves
+    unresolved exactly 0 (a nonzero payload there must not leak)."""
+    from repro.core.decoder import decode_layout, pick_tile_lanes
+    from repro.kernels.ldpc_peel import (peel_decode_pallas,
+                                         peel_decode_symbol_major_pallas)
+
+    code = make_regular_ldpc(20, l=3, r=6, seed=0)
+    H = jnp.asarray(code.H, jnp.float32)
+    rng = np.random.default_rng(V + D)
+    cw = jnp.asarray(code.encode(rng.standard_normal((code.K, V))),
+                     jnp.float32)
+    erased = jnp.asarray(_gradagg_mask(kind))
+    rx = jnp.where(erased[:, None], 0.0, cw)
+    if V >= 512:
+        assert decode_layout("pallas", V) == "symbol_major"
+        sym_v, sym_e = peel_decode(code, cw, erased, D, backend="pallas")[:2]
+    else:
+        assert decode_layout("pallas", V) == "lane_major"
+        sym_v, sym_e = peel_decode_symbol_major_pallas(
+            H, cw, erased, D, max_degree=code.check_idx.shape[1],
+            bv=pick_tile_lanes(code, V))
+    sp = peel_decode(code, rx, erased, D, backend="sparse")
+    lane_v, lane_e = peel_decode_pallas(H, rx, erased, D)
+    np.testing.assert_array_equal(np.asarray(sym_e), np.asarray(sp.erased))
+    np.testing.assert_array_equal(np.asarray(sym_e), np.asarray(lane_e))
+    if kind == "unresolvable" and D:
+        assert 0 < int(sym_e.sum()) < int(erased.sum())
+    got = np.asarray(sym_v)
+    for ref in (sp.values, lane_v):
+        np.testing.assert_allclose(got, np.asarray(ref), rtol=1e-3, atol=1e-3)
+    assert (got[np.asarray(sym_e)] == 0.0).all()
+
+
+def test_symbol_major_is_one_kernel_launch():
+    """The trajectory solve is XLA on the (p, N) H and the mask; the
+    payload pass is one pallas_call."""
+    from repro.kernels.ldpc_peel.ops import _peel_decode_symbol_major_impl
+
+    code = make_regular_ldpc(20, l=3, r=6, seed=0)
+    H = jnp.asarray(code.H, jnp.float32)
+    v = jnp.zeros((code.N, 1024), jnp.float32)
+    e = jnp.zeros((code.N,), bool)
+    fn = _peel_decode_symbol_major_impl.__wrapped__
+    jaxpr = jax.make_jaxpr(lambda H, v, e: fn(
+        H, v, e, iters=10, slots=5, bv=512, chunk=512, interpret=True))(H, v, e)
+    assert str(jaxpr).count("pallas_call") == 1
+
+
+def test_decode_layout_follows_the_payload_shape():
+    """Symbol-major only for the resident "pallas" fixed-D decode of a
+    payload at least 512 lanes wide, whatever N; each trace of a fixed-D
+    Pallas decode counts its layout once."""
+    from repro.core.decoder import decode_layout, vmem_bytes_estimate
+    from repro.obs import metrics, report
+
+    code = make_regular_ldpc(20, l=3, r=6, seed=0)          # N = 40
+    assert decode_layout("pallas", 4096) == "symbol_major"
+    assert decode_layout("pallas", 512) == "symbol_major"
+    assert decode_layout("pallas", 511) == "lane_major"
+    assert decode_layout("pallas", 128) == "lane_major"
+    assert decode_layout("pallas", 1) == "lane_major"
+    # the lsq cells' code: too big for the resident kernel, so tiled
+    assert vmem_bytes_estimate((8192, 16384)) > 8 * 2**20
+    for backend in ("pallas_tiled", "pallas_seeded", "sparse", "dense"):
+        assert decode_layout(backend, 1) == "lane_major"
+        assert decode_layout(backend, 4096) == "lane_major"
+
+    e = jnp.zeros((code.N,), bool)
+
+    def layouts(*Vs):
+        with metrics.recording() as reg:
+            for V in Vs:
+                jax.eval_shape(lambda v: peel_decode(
+                    code, v, e, 10, backend="pallas").values,
+                    jax.ShapeDtypeStruct((code.N, V), jnp.float32))
+        entries = list(reg.snapshot().values())
+        return {v["labels"]["layout"]: int(v["value"]) for v in entries
+                if v["name"] == "decoder.layout_total"}, entries
+
+    assert layouts(4096)[0] == {"symbol_major": 1}
+    assert layouts(1, 8)[0] == {"lane_major": 2}
+    counts, entries = layouts(4096, 1)
+    assert counts == {"symbol_major": 1, "lane_major": 1}
+    text = report.summarize({}, entries)
+    assert "layout[layout=symbol_major]: 1" in text
+    assert "layout[layout=lane_major]: 1" in text
+
+
 def test_neighbor_table_invariants():
     for code in (make_regular_ldpc(64, l=3, r=6, seed=1),
                  make_ldgm(32, 16, row_weight=4, seed=1)):

@@ -187,6 +187,28 @@ def test_engine_gradient_batch_matches_loop():
                                        rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("backend,V,decode_erases", [
+    ("pallas", 512, True),            # symbol-major: the kernel zeroes rows
+    ("pallas", 4, False),             # lane-major
+    ("pallas_tiled", 256, False),
+    ("sparse", 256, False),
+])
+def test_recover_ignores_erased_values(backend, V, decode_erases):
+    """``recover`` equals erase → decode → epilogue bit for bit, with NaN
+    on the erased rows, whether the erase runs or the symbol-major decode
+    zeroes those rows itself."""
+    code = make_regular_ldpc(20, l=3, r=6, seed=0)
+    eng = CodedComputeEngine(code, backend=backend, decode_iters=10)
+    rng = np.random.default_rng(V)
+    z = eng.encode(jnp.asarray(rng.standard_normal((code.K, V)), jnp.float32))
+    mask = jnp.asarray(np.isin(np.arange(code.N), [0, 3, 7, 21, 30]))
+    assert eng._decode_erases(z) is decode_erases
+    got_v, got_u = eng.recover(jnp.where(mask[:, None], jnp.nan, z), mask)
+    want_v, want_u = eng.systematic(eng.decode(eng.erase(z, mask), mask))
+    np.testing.assert_array_equal(np.asarray(got_u), np.asarray(want_u))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+
+
 def test_engine_rejects_unknown_backend():
     code = make_regular_ldpc(20, l=3, r=6, seed=0)
     with pytest.raises(ValueError):

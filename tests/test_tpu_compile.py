@@ -141,6 +141,39 @@ def test_kernel_launch_is_scoped_and_keeps_its_name(one_chip):
     assert "/kernel/decode_fused/pallas_call" in call
 
 
+def _symbol_major_launch(one_chip, p: int = 20, n: int = 40,
+                         V: int = 655360):
+    """By default the gradient-bucket cell's decode at a tenth of its
+    width: the (40, 20) code, a (40, V) payload, D = 10, the decoder's
+    lane tile."""
+    from repro.core.decoder import pick_tile_lanes
+
+    bv = pick_tile_lanes((p, n), V)
+    S = lambda *a: _spec(one_chip, *a)
+    return _compile(ops._peel_decode_symbol_major_impl, S((p, n)), S((n, V)),
+                    S((n,), jnp.bool_), iters=10, slots=5, bv=bv, chunk=512,
+                    interpret=False)
+
+
+@pytest.mark.parametrize("shape", ["gradagg", "resident"])
+def test_symbol_major_fixed(one_chip, shape):
+    """Unlike the replay kernel, the symbol-major decode lowers: its rows
+    are read at dynamic sublane offsets from SMEM indices, not gathered.
+    It fits VMEM for the gradient-bucket code and for the largest code
+    ``auto`` sends to the resident kernel (the narrowest lane tile)."""
+    p, n = (20, 40) if shape == "gradagg" else _resident_shape()
+    compiled = _symbol_major_launch(one_chip, p, n, V=max(65536, n))
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_symbol_major_launch_is_scoped_and_keeps_its_name(one_chip):
+    hlo = _symbol_major_launch(one_chip).as_text()
+    call = next(line for line in hlo.splitlines()
+                if "tpu_custom_call" in line)
+    assert call.lstrip().startswith("%decode_symbol_major.")
+    assert "/kernel/decode_symbol_major/pallas_call" in call
+
+
 # Kernels whose in-kernel gathers Mosaic rejects: a compiled launch must
 # fail up front with a clear error, never deep in the compiler (or fall
 # back to another backend unannounced).  No topology needed.
