@@ -2,7 +2,8 @@
 architecture (plus the paper's own experiment configs)."""
 from repro.configs.base import ArchConfig, get_config, list_configs, register
 
-# Assigned architectures (10) — each module registers itself on import.
+# Assigned architectures (10), plus deepseek-v2-lite (the benchmark's
+# training model) — each module registers itself on import.
 from repro.configs import (  # noqa: F401
     qwen3_1p7b,
     codeqwen1p5_7b,
@@ -10,6 +11,7 @@ from repro.configs import (  # noqa: F401
     whisper_medium,
     minitron_8b,
     deepseek_v2,
+    deepseek_v2_lite,
     kimi_k2,
     qwen2_1p5b,
     internvl2_2b,

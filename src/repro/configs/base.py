@@ -27,6 +27,11 @@ class MoESpec:
     capacity_factor: float = 1.25
     every: int = 1             # MoE ffn every `every` layers (jamba: 2)
     first_dense: int = 0       # leading layers with dense FFN (dsv2/kimi: 1)
+    norm_topk_prob: bool = True  # renormalise the top-k gates (dsv2: False)
+    # one chip's share of an expert-parallel layer: the experts
+    # [held_offset, held_offset + held) of n_experts (0 = all of them)
+    held: int = 0
+    held_offset: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,7 +149,8 @@ class ArchConfig:
             changes["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=2, d_ff_expert=2 * d_model,
                 n_shared=min(self.moe.n_shared, 1),
-                first_dense=min(self.moe.first_dense, 1))
+                first_dense=min(self.moe.first_dense, 1), held=0,
+                held_offset=0)
         if self.mla:
             changes["mla"] = MLASpec(kv_lora=32, q_lora=16 if self.mla.q_lora else 0,
                                      qk_nope=hd // 2, qk_rope=hd // 4, v_head=hd // 2)

@@ -23,7 +23,7 @@ from repro.core.straggler import (
     DelayModel,
     ScheduledDelays,
 )
-from repro.core.grad_agg import CodedAggregator, flatten_grads
+from repro.core.grad_agg import BucketLayout, CodedAggregator
 from repro.core.padding import pad_axis_to, pad_blocks
 
 __all__ = [
@@ -37,6 +37,6 @@ __all__ = [
     "Scheme1", "Scheme2", "Scheme2Blocked", "run_pgd", "RunResult",
     "Scheme", "scheme_registry",
     "BernoulliStragglers", "FixedCountStragglers", "AdversarialStragglers", "DelayModel", "ScheduledDelays",
-    "CodedAggregator", "flatten_grads",
+    "CodedAggregator", "BucketLayout",
     "pad_axis_to", "pad_blocks",
 ]

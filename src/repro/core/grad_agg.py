@@ -22,7 +22,7 @@ the plain gradient all-reduce; see launch/train.py's ``--coded-agg`` flag.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+import math
 
 import jax
 import jax.numpy as jnp
@@ -30,29 +30,60 @@ import jax.numpy as jnp
 from repro.core.engine import CodedComputeEngine
 from repro.core.ldpc import LDPCCode, make_ldgm
 
-__all__ = ["CodedAggregator", "flatten_grads", "unflatten_grads"]
+__all__ = ["CodedAggregator", "BucketLayout", "DDP_BUCKET_ELEMS"]
+
+# PyTorch DDP's default bucket_cap_mb (25 MiB of float32)
+DDP_BUCKET_ELEMS = 25 * 2 ** 20 // 4
 
 
-def flatten_grads(tree) -> tuple[jax.Array, Callable]:
-    """Flatten a gradient pytree to a single vector (and an inverse)."""
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    shapes = [l.shape for l in leaves]
-    sizes = [l.size for l in leaves]
-    flat = jnp.concatenate([l.reshape(-1) for l in leaves]) if leaves else jnp.zeros((0,))
+@dataclasses.dataclass(frozen=True)
+class BucketLayout:
+    """A parameter group's gradient cut into equal buckets of ``cap``
+    float32 (the last one zero-padded), so that the coded reduce runs one
+    bucket at a time, as data-parallel training reduces its buckets during
+    the backward pass, and never holds the whole model K shards deep.
 
-    def unflatten(vec):
+    ``cap`` is at most ``max_cap`` and at least 512 (a multiple of 512): a
+    group smaller than ``max_cap`` is one bucket of its own size.
+    """
+
+    treedef: object
+    shapes: tuple
+    cap: int
+
+    @classmethod
+    def of(cls, tree, max_cap: int = DDP_BUCKET_ELEMS) -> "BucketLayout":
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        shapes = tuple(tuple(l.shape) for l in leaves)
+        size = sum(math.prod(sh) for sh in shapes)
+        cap = min(max_cap, size)
+        return cls(treedef, shapes, -(-cap // 512) * 512)
+
+    @property
+    def size(self) -> int:
+        return sum(math.prod(sh) for sh in self.shapes)
+
+    @property
+    def n_buckets(self) -> int:
+        return -(-self.size // self.cap)
+
+    def pack(self, tree) -> tuple:
+        """One gradient tree -> ``n_buckets`` float32 ``(cap,)`` buckets."""
+        leaves = self.treedef.flatten_up_to(tree)
+        flat = jnp.concatenate([l.reshape(-1).astype(jnp.float32)
+                                for l in leaves])
+        flat = jnp.pad(flat, (0, self.n_buckets * self.cap - self.size))
+        return tuple(flat.reshape(self.n_buckets, self.cap))
+
+    def unpack(self, buckets):
+        """``n_buckets`` ``(cap,)`` sums -> the gradient tree."""
+        flat = jnp.concatenate(list(buckets))
         out, off = [], 0
-        for sh, sz in zip(shapes, sizes):
-            out.append(vec[off : off + sz].reshape(sh))
-            off += sz
-        return jax.tree_util.tree_unflatten(treedef, out)
-
-    return flat, unflatten
-
-
-def unflatten_grads(vec, like):
-    _, unflat = flatten_grads(like)
-    return unflat(vec)
+        for sh in self.shapes:
+            n = math.prod(sh)
+            out.append(flat[off:off + n].reshape(sh))
+            off += n
+        return jax.tree_util.tree_unflatten(self.treedef, out)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -27,13 +27,15 @@ NEG_INF = -1e30
 
 
 def sdpa_chunked(q, k, v, q_pos, kv_pos, *, causal=True, chunk=512,
-                 kv_valid=None, unroll=False) -> jax.Array:
+                 kv_valid=None, unroll=False, remat=False) -> jax.Array:
     """Chunked scaled-dot-product attention.
 
     q: (B, Sq, KV, G, Dh) — query heads grouped per KV head (GQA).
     k, v: (B, T, KV, Dh).
     q_pos: (Sq,) absolute positions of queries; kv_pos: (T,) of keys.
     kv_valid: optional (T,) bool — e.g. ring-buffer slots actually filled.
+    remat: recompute each chunk's scores in the backward pass instead of
+    keeping them (a chunk's score matrix at a time, not the whole S x T).
     """
     B, Sq, KV, G, Dh = q.shape
     scale = 1.0 / math.sqrt(Dh)
@@ -50,6 +52,8 @@ def sdpa_chunked(q, k, v, q_pos, kv_pos, *, causal=True, chunk=512,
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bkgqt,btkd->bqkgd", p, v.astype(jnp.float32)).astype(v.dtype)
 
+    if remat:
+        block = jax.checkpoint(block)
     cq = min(chunk, Sq)
     if Sq % cq != 0 or Sq == cq:
         return block(q, q_pos)
@@ -217,7 +221,8 @@ def _mla_q(p, x, n_heads, qk_nope, qk_rope, positions, rope_theta):
 
 
 def mla_forward(p, x, *, n_heads, kv_lora, qk_nope=128, qk_rope=64, v_head=128,
-                positions, rope_theta=1e6, chunk=512, cache=None, unroll=False):
+                positions, rope_theta=1e6, chunk=512, cache=None, unroll=False,
+                remat=False):
     """Naive (non-absorbed) MLA for training/prefill: materialize per-head K/V."""
     B, S, _ = x.shape
     q_nope, q_rope = _mla_q(p, x, n_heads, qk_nope, qk_rope, positions, rope_theta)
@@ -240,7 +245,7 @@ def mla_forward(p, x, *, n_heads, kv_lora, qk_nope=128, qk_rope=64, v_head=128,
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
     # MLA has per-head K (no grouping): KV = n_heads, G = 1.
     o = sdpa_chunked(q_full[:, :, :, None, :], k_full, v, positions, positions,
-                     causal=True, chunk=chunk, unroll=unroll)
+                     causal=True, chunk=chunk, unroll=unroll, remat=remat)
     y = dense(p["wo"], o.reshape(B, S, n_heads * v_head))
     return y, cache
 

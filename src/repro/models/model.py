@@ -57,7 +57,7 @@ class Model:
     def __init__(self, cfg: ArchConfig, *, remat: bool = True,
                  moe_aux_coef: float = 0.01, attn_chunk: int = 512,
                  unroll: bool = False, moe_groups: int = 1,
-                 mamba_chunk: int | None = None):
+                 mamba_chunk: int | None = None, attn_remat: bool = False):
         # unroll=True: python loops instead of lax.scan over layer blocks and
         # attention chunks, so compiled.cost_analysis() counts every
         # iteration's flops (XLA counts while-loop bodies once). Used by the
@@ -69,6 +69,9 @@ class Model:
         self.unroll = unroll
         self.moe_groups = moe_groups  # §Perf H2: data-aligned MoE routing groups
         self.mamba_chunk = mamba_chunk  # chunked parallel-in-time SSM prefill
+        # recompute MLA score chunks in the backward pass (a layer's
+        # gradient then holds one chunk's scores, not all of S x S)
+        self.attn_remat = attn_remat
         self.prefix_len, self.period = cfg.stack_plan()
         self.n_blocks = (cfg.n_layers - self.prefix_len) // self.period
         self.specs = cfg.layer_specs()
@@ -120,7 +123,8 @@ class Model:
             p["ffn"] = MOE.init_moe(ks[2], cfg.d_model, cfg.moe.d_ff_expert,
                                     cfg.moe.n_experts, n_shared=cfg.moe.n_shared,
                                     act=cfg.act if cfg.act != "relu2" else "swiglu",
-                                    dtype=dt)
+                                    dtype=dt, held=cfg.moe.held,
+                                    expert_offset=cfg.moe.held_offset)
         elif ffn == "rwkv_cm":
             p["ffn"] = S.init_rwkv_channel(ks[2], cfg.d_model, cfg.d_ff, dtype=dt)
         return p
@@ -161,8 +165,9 @@ class Model:
 
     # ------------------------------------------------------- layer apply ---
     def _apply_layer(self, p, spec, x, *, positions, mode, cache=None, pos=None,
-                     enc_out=None, rng=None):
-        """Returns (x, new_cache, aux_loss)."""
+                     enc_out=None, rng=None, stats=None):
+        """Returns (x, new_cache, aux_loss).  A ``stats`` dict receives the
+        layer's MoE ``dropped`` count (assignments over capacity)."""
         cfg = self.cfg
         mixer, ffn = spec
         aux = jnp.zeros((), jnp.float32)
@@ -208,10 +213,12 @@ class Model:
             kw = dict(n_heads=cfg.n_heads, kv_lora=m.kv_lora, qk_nope=m.qk_nope,
                       qk_rope=m.qk_rope, v_head=m.v_head, rope_theta=cfg.rope_theta)
             if mode in ("train", "prefill"):
-                y, new_cache = A.mla_forward(
-                    p["mixer"], h, positions=positions, chunk=self.attn_chunk,
-                    unroll=self.unroll,
-                    cache=cache if mode == "prefill" else None, **kw)
+                with jax.named_scope("mla"):
+                    y, new_cache = A.mla_forward(
+                        p["mixer"], h, positions=positions,
+                        chunk=self.attn_chunk, unroll=self.unroll,
+                        remat=self.attn_remat,
+                        cache=cache if mode == "prefill" else None, **kw)
             else:
                 y, new_cache = A.mla_decode(p["mixer"], h, pos=pos, cache=cache, **kw)
             x = x + y
@@ -253,11 +260,15 @@ class Model:
         if ffn == "dense":
             x = x + mlp(p["ffn"], h, act=cfg.act)
         elif ffn == "moe":
-            y, aux = MOE.moe_forward(p["ffn"], h, n_experts=cfg.moe.n_experts,
-                                     top_k=cfg.moe.top_k,
-                                     capacity_factor=cfg.moe.capacity_factor,
-                                     act=cfg.act if cfg.act != "relu2" else "swiglu",
-                                     key=rng, groups=self.moe_groups)
+            y, aux, dropped = MOE.moe_forward(
+                p["ffn"], h, n_experts=cfg.moe.n_experts, top_k=cfg.moe.top_k,
+                capacity_factor=cfg.moe.capacity_factor,
+                act=cfg.act if cfg.act != "relu2" else "swiglu",
+                key=rng, groups=self.moe_groups,
+                norm_topk_prob=cfg.moe.norm_topk_prob,
+                expert_offset=cfg.moe.held_offset, return_dropped=True)
+            if stats is not None:
+                stats["dropped"] = dropped
             x = x + y
         elif ffn == "rwkv_cm":
             if mode in ("train", "prefill", "encode"):

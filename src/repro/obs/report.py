@@ -149,6 +149,16 @@ def summarize(meta: dict, entries: list[dict]) -> str:
             add(f"  staleness_weight[{_label(e)}]: "
                 f"mean={_fmt(_hist_mean(e))}")
 
+    if "grad_agg.buckets_total" in by:
+        add("")
+        add("== coded training step ==")
+        for e in by.get("grad_agg.buckets_total", []):
+            add(f"  buckets[{_label(e)}]: {int(e['value'])}")
+        for nm, what in (("grad_agg.unresolved_total", "unresolved_shards"),
+                         ("moe.tokens_dropped_total", "moe_tokens_dropped")):
+            for e in by.get(nm, []):
+                add(f"  {what}[{_label(e)}]: {int(e['value'])}")
+
     if "serving.admission_wait_s" in by or "serving.submitted_total" in by:
         add("")
         add("== serving ==")

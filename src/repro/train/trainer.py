@@ -9,7 +9,8 @@ Two gradient paths:
                  hold small shard unions, a straggler mask erases worker
                  symbols, and the master peels for D rounds.  Unresolved
                  shards are zero-filled => unbiased (1-q_D)-scaled gradient
-                 (Lemma 1 verbatim).
+                 (Lemma 1 verbatim).  The reduce runs layer by layer, one
+                 gradient bucket at a time (train/coded_step.py).
 
 This is the runnable CPU-scale driver (examples/train_llm.py); the
 production-mesh path is exercised by launch/dryrun.py.
@@ -23,11 +24,12 @@ from typing import Any, Callable, Iterator
 import jax
 import jax.numpy as jnp
 
-from repro.core.grad_agg import CodedAggregator, flatten_grads
+from repro.core.grad_agg import CodedAggregator
 from repro.core.straggler import BernoulliStragglers
 from repro.models import Model
 from repro.optim import AdamWConfig, adamw_init, adamw_update
 from repro.train.checkpoint import save_checkpoint
+from repro.train.coded_step import CodedTrainStep
 
 __all__ = ["TrainerConfig", "Trainer"]
 
@@ -72,39 +74,7 @@ class Trainer:
                 return params, opt_state, loss, jnp.int32(0)
             return step
 
-        K = tcfg.n_shards
-
-        @jax.jit
-        def step(params, opt_state, batch, key):
-            # shard the batch leaves along the batch dim into K micro-shards
-            def shard(leaf):
-                B = leaf.shape[0]
-                if B % K != 0:
-                    raise ValueError(
-                        f"batch size {B} not divisible by n_shards={K}")
-                return leaf.reshape(K, B // K, *leaf.shape[1:])
-            sharded = jax.tree.map(shard, batch)
-
-            def shard_loss(params, i):
-                b = jax.tree.map(lambda l: l[i], sharded)
-                return model.loss_fn(params, b)
-
-            def shard_grad(i):
-                g = jax.grad(shard_loss)(params, i)
-                flat, _ = flatten_grads(g)
-                return flat / K  # each shard contributes 1/K of the mean loss
-
-            partials = jax.lax.map(shard_grad, jnp.arange(K))  # (K, dim)
-            mask = self.straggler.sample(key, agg.n_workers)
-            total, unresolved = agg.aggregate(partials, mask)
-            # grads have exactly the params tree structure/shapes
-            _, unflat = flatten_grads(params)
-            grads = unflat(total)
-            loss = model.loss_fn(params, batch)
-            params, opt_state = adamw_update(params, grads, opt_state, tcfg.opt)
-            return params, opt_state, loss, unresolved
-
-        return step
+        return CodedTrainStep(model, agg, tcfg.opt)
 
     def fit(self, params, batches: Iterator[dict], *, key=None,
             callback: Callable[[int, float], None] | None = None):
@@ -115,8 +85,16 @@ class Trainer:
         for step_i in range(self.tcfg.steps):
             batch = next(batches)
             key, k1 = jax.random.split(key)
-            params, opt_state, loss, unresolved = self._step_fn(
-                params, opt_state, batch, k1)
+            if self.tcfg.coded_agg:
+                # the mask is drawn outside the step: the step takes the
+                # workers that straggled as given
+                mask = self.straggler.sample(k1, self.agg.n_workers)
+                res = self._step_fn(params, opt_state, batch, mask)
+                params, opt_state = res.params, res.opt_state
+                loss, unresolved = res.loss, res.unresolved
+            else:
+                params, opt_state, loss, unresolved = self._step_fn(
+                    params, opt_state, batch, k1)
             loss = float(loss)
             history.append(loss)
             if callback:

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import BernoulliStragglers, CodedAggregator
-from repro.core.grad_agg import flatten_grads
+from repro.core.grad_agg import BucketLayout
 
 
 def test_zero_stragglers_exact_sum():
@@ -67,12 +67,20 @@ def test_bernoulli_unbiased_scaled():
 
 
 def test_flatten_roundtrip():
-    tree = {"a": jnp.ones((2, 3)), "b": {"c": jnp.arange(4.0)}}
-    flat, unflat = flatten_grads(tree)
-    assert flat.shape == (10,)
-    rt = unflat(flat)
+    """A gradient tree cut into equal buckets (the last zero-padded) and
+    put back together."""
+    tree = {"a": jnp.arange(700.0).reshape(7, 100),
+            "b": {"c": jnp.arange(5.0)}}
+    lay = BucketLayout.of(tree, max_cap=512)
+    assert (lay.cap, lay.n_buckets, lay.size) == (512, 2, 705)
+    buckets = lay.pack(tree)
+    assert [b.shape for b in buckets] == [(512,), (512,)]
+    np.testing.assert_array_equal(buckets[1][705 - 512:], 0.0)
+    rt = lay.unpack(buckets)
     np.testing.assert_allclose(rt["a"], tree["a"])
     np.testing.assert_allclose(rt["b"]["c"], tree["b"]["c"])
+    # a group smaller than the cap is one bucket, at least 512 lanes
+    assert BucketLayout.of({"x": jnp.ones(10)}).cap == 512
 
 
 def test_end_to_end_coded_training_linear_model():
