@@ -1,7 +1,9 @@
 """CPU rehearsal of the coded-training cell at small widths through the
 harness's own ``run``: correct on the program, not correct on the control
 (the reference at an 8-bit mantissa) and under each fault the comparison
-must catch; its work counts at the configuration's size."""
+must catch (workers erased, a shard dropped, the sum altered, a NaN, the
+parameters left unchanged, AdamW at the wrong step size); its work counts
+at the configuration's size."""
 import io
 import json
 import time
@@ -40,8 +42,10 @@ def test_cell_is_correct():
     result, err = _run()
     assert result["correct"], result["compared"]
     assert set(result["compared"]) == {"unresolved_gap", "grad_err",
+                                       "grad_err_tree", "update_err",
                                        "loss_err"}
-    assert result["compared"]["grad_err"]["value"] < 1e-4
+    assert all(result["compared"][k]["value"] < 1e-4
+               for k in ("grad_err", "grad_err_tree", "update_err"))
     assert set(result["metrics"]) == {"step_ms", "setup_s"}
     assert '"setup": "build_s"' in err
 
@@ -65,6 +69,20 @@ def _fault(monkeypatch, fault):
     from repro.core.grad_agg import CodedAggregator
     from repro.train.coded_step import CodedTrainStep
 
+    if fault in ("unchanged", "lr"):
+        orig_opt = CodedTrainStep._opt_fn
+
+        def opt_fn(self):
+            if fault == "lr":         # AdamW at twice its step size
+                self.opt = self.opt._replace(lr=2 * self.opt.lr)
+                return orig_opt(self)
+
+            def train_opt_program(params, opt_state, sums):
+                return params, opt_state     # the state left unchanged
+            return train_opt_program
+
+        monkeypatch.setattr(CodedTrainStep, "_opt_fn", opt_fn)
+        return
     if fault == "half":           # half the workers' symbols left out
         orig_step = CodedTrainStep.__call__
 
@@ -90,7 +108,8 @@ def _fault(monkeypatch, fault):
     monkeypatch.setattr(CodedAggregator, "aggregate", aggregate)
 
 
-@pytest.mark.parametrize("fault", ["half", "dropped", "altered", "nan"])
+@pytest.mark.parametrize("fault", ["half", "dropped", "altered", "nan",
+                                   "unchanged", "lr"])
 def test_fault_is_not_correct(fault, monkeypatch):
     _fault(monkeypatch, fault)
     result, _ = _run()

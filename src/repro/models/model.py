@@ -266,7 +266,7 @@ class Model:
                 act=cfg.act if cfg.act != "relu2" else "swiglu",
                 key=rng, groups=self.moe_groups,
                 norm_topk_prob=cfg.moe.norm_topk_prob,
-                expert_offset=cfg.moe.held_offset, return_dropped=True)
+                expert_offset=cfg.moe.held_offset)
             if stats is not None:
                 stats["dropped"] = dropped
             x = x + y

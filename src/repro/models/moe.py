@@ -68,10 +68,9 @@ def _expert_mlp(experts, xe, act: str):
 def moe_forward(p, x, *, n_experts: int, top_k: int, capacity_factor: float = 1.25,
                 act: str = "swiglu", router_noise: float = 0.0, key=None,
                 groups: int = 1, norm_topk_prob: bool = True,
-                expert_offset: int = 0, return_dropped: bool = False):
-    """x: (B, S, d) -> (y, aux_loss), or (y, aux_loss, dropped) with
-    ``return_dropped``: the number of this share's routed assignments that
-    its capacity dropped.
+                expert_offset: int = 0):
+    """x: (B, S, d) -> (y, aux_loss, dropped), ``dropped`` the number of
+    this share's routed assignments that its capacity dropped.
 
     Tokens are routed to their top-k experts; each expert processes at most C
     tokens (overflow dropped — standard capacity-based MoE).  The top-k gate
@@ -99,7 +98,7 @@ def moe_forward(p, x, *, n_experts: int, top_k: int, capacity_factor: float = 1.
         out, aux, dropped = _moe_tokens(p, x.reshape(B * S, d), key=key,
                                         router_noise=router_noise, **kw)
         out = out.reshape(B, S, d)
-    return (out, aux, dropped) if return_dropped else (out, aux)
+    return out, aux, dropped
 
 
 def _moe_tokens(p, xt, *, n_experts: int, top_k: int, capacity_factor: float,

@@ -95,7 +95,7 @@ def test_expert_shares_add_up_to_the_whole_layer(norm):
     expert counted once, are the uncut layer's output."""
     d, E, T = 32, 8, 48
     kw = dict(n_experts=E, top_k=3, capacity_factor=0.7,
-              norm_topk_prob=norm, return_dropped=True)
+              norm_topk_prob=norm)
     fwd = jax.jit(MOE.moe_forward, static_argnames=(*kw, "expert_offset"))
     whole = MOE.init_moe(jax.random.PRNGKey(0), d, 16, E, n_shared=1)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, T // 2, d))
@@ -131,8 +131,8 @@ def test_gates_not_renormalised():
     for norm in (False, True):
         w = g / g.sum(-1, keepdims=True) if norm else g
         want = jnp.einsum("tk,ktd->td", w, picked)
-        y, _ = MOE.moe_forward(p, x, n_experts=E, top_k=2,
-                               capacity_factor=8.0, norm_topk_prob=norm)
+        y, _, _ = MOE.moe_forward(p, x, n_experts=E, top_k=2,
+                                  capacity_factor=8.0, norm_topk_prob=norm)
         np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)
     assert float(g.sum(-1).max()) < 0.999      # the two choices differ
 

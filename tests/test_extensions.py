@@ -58,9 +58,9 @@ def test_moe_grouped_matches_ungrouped_high_capacity(seed, groups):
     key = jax.random.PRNGKey(seed)
     p = MOE.init_moe(key, 16, 32, 4, dtype=jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 4, 16))
-    y1, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2, capacity_factor=16.0)
-    y2, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2, capacity_factor=16.0,
-                            groups=groups)
+    y1, _, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2, capacity_factor=16.0)
+    y2, _, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2,
+                               capacity_factor=16.0, groups=groups)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                rtol=2e-4, atol=2e-4)
 
@@ -71,8 +71,10 @@ def test_moe_capacity_dropping_monotone():
     key = jax.random.PRNGKey(0)
     p = MOE.init_moe(key, 16, 32, 4, dtype=jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 1), (2, 32, 16))
-    y_lo, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2, capacity_factor=0.25)
-    y_hi, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2, capacity_factor=8.0)
+    y_lo, _, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2,
+                                 capacity_factor=0.25)
+    y_hi, _, _ = MOE.moe_forward(p, x, n_experts=4, top_k=2,
+                                 capacity_factor=8.0)
     assert np.isfinite(np.asarray(y_lo)).all()
     # dropped tokens contribute 0 -> lower-capacity output has smaller norm
     assert float(jnp.linalg.norm(y_lo)) <= float(jnp.linalg.norm(y_hi)) + 1e-3
