@@ -70,6 +70,27 @@ backend    what runs
            a tile's row-sum reduction differently than the whole-H one).
            Tile knobs: ``bp`` (check-tile height; default sized from the
            VMEM budget via :func:`pick_tile_bp`) and ``bv`` (payload tile).
+
+           Layout (:func:`decode_layout`): on TPU the fixed-D
+           single-pattern decode of a narrow payload (``V <=
+           _EDGE_PERMUTED_MAX_V``) runs EDGE-PERMUTED instead, and reads
+           no H: the code's edges are laid out twice, variable-major and
+           check-major, as slot-major planes of whole vregs, and a Beneš
+           network routed once per code on the host
+           (:attr:`LDPCCode.edge_network`, :mod:`repro.core.benes`)
+           permutes between the two.  Each round broadcasts values and
+           erasure flags onto the variable-major edges, permutes them,
+           counts and sums each check over its planes, proposes on the
+           erased edge of each solvable check, permutes back and keeps
+           each variable's lowest resolving check: the same trajectory,
+           in one launch with the carry in VMEM, ending early at the
+           first round that resolves nothing (the state is then final)
+           (:func:`repro.kernels.ldpc_peel.peel_decode_edge_permuted_pallas`).
+           Taken where the network's working set fits the VMEM budget;
+           the batched and adaptive entry points keep the streamed kernel.
+           Its cost grows with V, one payload column a grid step; the
+           streamed kernel's does not, and the two cross near V = 11 at
+           N = 16384 on one TPU v5e (PERF.md, PR 16).
 "pallas_seeded"
            the same four one-launch contracts with NO ``H`` operand at all:
            each ``bp×N`` check tile is REGENERATED in-register from the
@@ -178,6 +199,7 @@ import jax.numpy as jnp
 from jax.lax import Precision
 import numpy as np
 
+from repro.core.benes import edge_geometry
 from repro.core.ldpc import (
     LDPCCode,
     SeededLDPC,
@@ -227,6 +249,12 @@ _DEFAULT_VMEM_BUDGET_BYTES = 8 * 2**20
 # TPU v5e the two cross near 340 lanes for N = 40 and for N = 896 alike
 # (PERF.md, the V sweep): symbol-major's trajectory solve is a fixed cost.
 _SYMBOL_MAJOR_MIN_V = 512
+# Widest payload the "pallas_tiled" fixed-D decode carries on the code's
+# edges (decode_layout); past it the streamed-H kernel stays.  On one TPU
+# v5e at N = 16384 the edge kernel took 1.41 ms a payload column against
+# 16.12 ms for the streamed decode of any width it fits: they cross near
+# V = 11 (PERF.md, PR 16's three-way timing).
+_EDGE_PERMUTED_MAX_V = 8
 
 
 def _kernel_shape(code) -> tuple[int, int]:
@@ -284,15 +312,24 @@ def pick_tile_bp(code, *, vmem_budget_bytes: int | None = None) -> int:
     return int(max(8, min(bp, p + (-p) % 8)))
 
 
-def decode_layout(backend: str, V: int) -> str:
+def decode_layout(backend: str, V: int, code=None, *,
+                  vmem_budget_bytes: int | None = None) -> str:
     """How a resolved fixed-D single-pattern decode lays out its payload:
     "symbol_major" where ``backend == "pallas"`` and the ``(N, V)`` payload
-    is at least ``_SYMBOL_MAJOR_MIN_V`` lanes wide, else "lane_major"
-    (every other Pallas kernel, and the XLA backends, which take the
-    payload as given).  The measured crossover was the same for N = 40
-    and N = 896."""
+    is at least ``_SYMBOL_MAJOR_MIN_V`` lanes wide; "edge_permuted" on TPU
+    where ``backend == "pallas_tiled"``, ``code`` is an :class:`LDPCCode`
+    whose edge network fits the VMEM budget and the payload is at most
+    ``_EDGE_PERMUTED_MAX_V`` wide; else "lane_major" (every other Pallas
+    kernel, and the XLA backends, which take the payload as given).  The
+    symbol-major crossover was the same for N = 40 and N = 896."""
     if backend == "pallas" and V >= _SYMBOL_MAJOR_MIN_V:
         return "symbol_major"
+    if (backend == "pallas_tiled" and V <= _EDGE_PERMUTED_MAX_V
+            and isinstance(code, LDPCCode)
+            and jax.default_backend() == "tpu"
+            and edge_geometry(code.check_idx, code.N).vmem_bytes
+            <= (vmem_budget_bytes or _DEFAULT_VMEM_BUDGET_BYTES)):
+        return "edge_permuted"
     return "lane_major"
 
 
@@ -383,11 +420,13 @@ def resolve_backend(backend: str, code, *, adaptive: bool = False,
     return backend
 
 
-def _count_layout(backend: str, V: int) -> str:
+def _count_layout(backend: str, V: int, code,
+                  vmem_budget_bytes: int | None) -> str:
     """:func:`decode_layout`, counted as ``decoder.layout_total{layout}``
     in the active registry: one increment per trace of a fixed-D Pallas
     decode, like ``decoder.resolve_total``."""
-    layout = decode_layout(backend, V)
+    layout = decode_layout(backend, V, code,
+                           vmem_budget_bytes=vmem_budget_bytes)
     reg = _obs_metrics.active()
     if reg is not None:
         reg.counter("decoder.layout_total", layout=layout).inc()
@@ -999,7 +1038,8 @@ def peel_decode(
                                              peel_decode_symbol_major_pallas)
 
         H = _dense_h(code, H, v.dtype)
-        if _count_layout(backend, v.shape[1]) == "symbol_major":
+        if _count_layout(backend, v.shape[1], code,
+                         vmem_budget_bytes) == "symbol_major":
             v, e = peel_decode_symbol_major_pallas(
                 H, v, e, iters, max_degree=code.check_idx.shape[1],
                 bv=pick_tile_lanes(code, v.shape[1],
@@ -1007,18 +1047,23 @@ def peel_decode(
         else:
             v, e = peel_decode_pallas(H, v, e, iters)
     elif backend == "pallas_tiled":
-        from repro.kernels.ldpc_peel import peel_decode_tiled_pallas
+        from repro.kernels.ldpc_peel import (peel_decode_edge_permuted_pallas,
+                                             peel_decode_tiled_pallas)
 
-        _count_layout(backend, v.shape[1])
-        bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
-        H = _dense_h(code, H, v.dtype)
-        v, e = peel_decode_tiled_pallas(H, v, e, iters, bp=bp_, bv=bv_)
+        if _count_layout(backend, v.shape[1], code,
+                         vmem_budget_bytes) == "edge_permuted":
+            v, e = peel_decode_edge_permuted_pallas(code.edge_network, v, e,
+                                                    iters)
+        else:
+            bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
+            H = _dense_h(code, H, v.dtype)
+            v, e = peel_decode_tiled_pallas(H, v, e, iters, bp=bp_, bv=bv_)
     elif backend == "pallas_seeded":
         from repro.kernels.ldpc_peel import peel_decode_seeded_pallas
 
         bp_, bv_ = _tile_knobs(code, bp, bv, vmem_budget_bytes)
         mode = _resolve_seeded_mode(seeded_mode, code, v.shape[1], bp_)
-        _count_layout(backend, v.shape[1])
+        _count_layout(backend, v.shape[1], code, vmem_budget_bytes)
         v, e = peel_decode_seeded_pallas(_seeded_spec(code), v, e, iters,
                                          bp=bp_, bv=bv_, mode=mode)
     else:

@@ -325,7 +325,8 @@ class CodedComputeEngine:
             return False
         backend = resolve_backend(self.backend, self.code,
                                   vmem_budget_bytes=self.vmem_budget_bytes)
-        return (decode_layout(backend, symbols.shape[1])
+        return (decode_layout(backend, symbols.shape[1], self.code,
+                              vmem_budget_bytes=self.vmem_budget_bytes)
                 == "symbol_major")
 
     def recover_batch(self, symbols: jax.Array, mask: jax.Array, *,

@@ -167,6 +167,16 @@ class LDPCCode:
         """(N, l_max) int32 incident check rows per variable, sentinel ``p``."""
         return self._var_table
 
+    @functools.cached_property
+    def edge_network(self):
+        """The routed variable-major → check-major edge permutation the
+        edge-permuted decode moves values through
+        (:func:`repro.core.benes.build_edge_network`); routed on first
+        use, once per code."""
+        from repro.core.benes import build_edge_network
+
+        return build_edge_network(self.check_idx, self.check_coeff, self.N)
+
     def encode(self, message: np.ndarray) -> np.ndarray:
         """Encode a (K, ...) message block into an (N, ...) codeword block."""
         if self.G.size == 0:

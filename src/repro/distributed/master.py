@@ -60,7 +60,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
 from repro.core.coded_step import Scheme2
-from repro.core.decoder import DecodeResult, resolve_backend
+from repro.core.decoder import DecodeResult, decode_layout, resolve_backend
 from repro.core.engine import blocked_epilogue
 from repro.core.straggler import DelayModel
 from repro.distributed.sharded_decode import (
@@ -96,10 +96,15 @@ MASTER_DECODES = ("single", "sharded", "replay")
 WORKER_ENCODES = ("materialized", "seeded", "seeded-fused")
 
 
-def master_decode_operand(engine, device):
+def master_decode_operand(engine, device, *,
+                          fixed_width: int | None = None):
     """The ``(p, N)`` f32 parity-check matrix the engine's decode reads,
-    placed on ``device`` once — or None when the resolved backend reads
-    none (sparse tables, seeded tiles and replay schedules are small).
+    placed on ``device`` once — or None when no program of the driver
+    reads one: the resolved backend reads none (sparse tables, seeded
+    tiles and replay schedules are small), or the driver's only decode is
+    the fixed-D single pattern of a ``fixed_width``-wide payload (None:
+    some program decodes otherwise, or the width is not known yet) and it
+    runs on the code's edge network.
 
     Master programs take it as an ARGUMENT and decode through
     ``dataclasses.replace(engine, H=H)``: closed over, the matrix would be
@@ -109,6 +114,11 @@ def master_decode_operand(engine, device):
                               adaptive=engine.adaptive,
                               vmem_budget_bytes=engine.vmem_budget_bytes)
     if backend not in ("dense", "pallas", "pallas_tiled"):
+        return None
+    if (fixed_width is not None and not engine.adaptive
+            and decode_layout(backend, fixed_width, engine.code,
+                              vmem_budget_bytes=engine.vmem_budget_bytes)
+            == "edge_permuted"):
         return None
     return jax.device_put(jnp.asarray(engine.code.H, jnp.float32), device)
 
@@ -291,8 +301,9 @@ class DistributedCodedGD:
             self._C_sharded = shard_encoded_rows(
                 jnp.asarray(self.scheme.C), self.mesh, self.topology)
         self.master_device = self.mesh.devices.flat[0]
-        self._decode_H = (master_decode_operand(self.scheme.engine,
-                                                self.master_device)
+        self._decode_H = (master_decode_operand(
+            self.scheme.engine, self.master_device,
+            fixed_width=1 if self.budget_mode == "fixed" else None)
                           if self.master_decode == "single" else None)
         if self.master_decode == "sharded":
             # Check tiles partitioned over the workers axis, once at build.

@@ -69,6 +69,7 @@ from repro.distributed.master import (
     _record_plan_metrics,
     _record_step_metrics,
     delay_step_control,
+    master_decode_operand,
 )
 from repro.distributed.telemetry import (
     ArrivalLagEstimator,
@@ -259,6 +260,12 @@ class AsyncDistributedCodedGD:
         self.master_device = self._sync.master_device
         self._replicated = self._sync._replicated
         self._master_cache: dict = {}
+        # The folds run the batched decode, which reads H wherever the
+        # sync driver's fixed-D decode runs on the edge network instead.
+        self._decode_H = self._sync._decode_H
+        if self._decode_H is None and self.master_decode == "single":
+            self._decode_H = master_decode_operand(self.scheme.engine,
+                                                   self.master_device)
         self._fold_program = self._build_fold_program()
         self._add = jax.jit(jnp.add)
 
@@ -564,7 +571,7 @@ class AsyncDistributedCodedGD:
                                    source_step=entry.step, lag=lag):
                             delta, u2, n_new, fr = self._fold_program(
                                 entry.z_m, remaining, entry.u, fold_budget,
-                                w_tau, sync._decode_H)
+                                w_tau, self._decode_H)
                         entry.u = u2
                         fold_newly.setdefault(entry.step, []).append(n_new)
                         fold_rounds_at.setdefault(t, []).append(fr)
@@ -594,7 +601,7 @@ class AsyncDistributedCodedGD:
                     sync._mshard(z), np.asarray(c.cut), theta_m, tbar_m,
                     fold_dg, np.float32(t),
                     np.asarray([c.budget], np.int32), tstar_m,
-                    sync._decode_H)
+                    self._decode_H)
 
             # 4. broadcast the new iterate (zero-copy on the master device:
             # the replicated put reuses θ's buffer for the master shard)

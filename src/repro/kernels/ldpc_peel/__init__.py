@@ -29,6 +29,11 @@ wide against the code: the trajectory is solved once per call on H and
 the mask, and one pass over lane tiles of the untransposed ``(N, V)``
 payload touches only the rows it resolves.
 
+``peel_decode_edge_permuted_pallas`` runs the fixed-D flooding rounds on
+the code's edge array instead of H: values move between the
+variable-major and check-major edge layouts through a Beneš network routed
+once per code, so no round reads more than the edges.
+
 ``peel_decode_replay_pallas`` drops the round structure entirely: it takes
 a precompiled ``repro.core.PeelSchedule`` (value-independent elimination
 order) and replays the resolved edges as one fused gather/FMA launch —
@@ -50,6 +55,7 @@ from repro.kernels.ldpc_peel.kernel import (
     decode_seeded_adaptive,
     decode_seeded_batch,
     decode_seeded_batch_adaptive,
+    decode_edge_permuted,
     decode_symbol_major,
     seeded_h_tile,
 )
@@ -63,6 +69,7 @@ from repro.kernels.ldpc_peel.ops import (
     peel_decode_batch_pallas,
     peel_decode_batch_seeded_pallas,
     peel_decode_batch_tiled_pallas,
+    peel_decode_edge_permuted_pallas,
     peel_decode_pallas,
     peel_decode_replay_pallas,
     peel_decode_seeded_pallas,
@@ -81,12 +88,13 @@ __all__ = ["peel_round_pallas", "peel_decode_pallas",
            "peel_decode_adaptive_seeded_pallas",
            "peel_decode_batch_adaptive_seeded_pallas",
            "peel_decode_replay_pallas", "peel_decode_symbol_major_pallas",
+           "peel_decode_edge_permuted_pallas",
            "check_pass", "decode_fused", "decode_fused_batch",
            "decode_fused_adaptive", "decode_fused_batch_adaptive",
            "decode_fused_tiled", "decode_fused_batch_tiled",
            "decode_fused_adaptive_tiled",
            "decode_fused_batch_adaptive_tiled",
-           "decode_replay", "decode_symbol_major",
+           "decode_replay", "decode_symbol_major", "decode_edge_permuted",
            "decode_seeded", "decode_seeded_batch",
            "decode_seeded_adaptive", "decode_seeded_batch_adaptive",
            "seeded_h_tile"]

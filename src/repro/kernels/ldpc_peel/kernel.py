@@ -41,6 +41,20 @@ see :func:`_check_tile_proposal` / :func:`_resident_round` /
   ``V >= 512`` lanes (measured crossover near 340 lanes on one v5e);
   below that the lane-major :func:`decode_fused` stays.
 
+* :func:`decode_edge_permuted` — a fixed-``D`` flooding decode on the
+  code's EDGE array, with no H operand: the edges are laid out
+  variable-major and check-major as slot-major planes of whole vregs, a
+  check's (a variable's) reduction is elementwise over its planes, and the
+  only data movement is the permutation between the two layouts, applied
+  by a Beneš network routed on the host (``core/benes.py``).  Each stage
+  is an XOR-partner exchange: two ``pltpu.roll``s and two selects inside a
+  vreg on bits 0-9 (lanes, then sublanes), a select between two vregs
+  from bit 10 up.  The switch bits ride as one int32 word per slot; the
+  stages run reversed apply the inverse.  All rounds run in one launch,
+  the carry in VMEM, and stop at the first round that resolves nothing
+  (every later round would leave the state as it is).  ``core/decoder.decode_layout`` takes it on TPU for
+  the fixed-D decode of a narrow payload that ``auto`` would stream H for.
+
 The in-kernel "scatter" is expressed MXU-style: the per-check resolution
 one-hot ``(bp, N)`` becomes the right operand of a matmul that moves each
 resolved coordinate's new value into place — TPUs have no efficient
@@ -140,9 +154,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.benes import stage_bits
 from repro.kernels import detect_interpret
 
 __all__ = ["check_pass", "decode_fused", "decode_fused_batch",
@@ -152,7 +168,7 @@ __all__ = ["check_pass", "decode_fused", "decode_fused_batch",
            "decode_seeded", "decode_seeded_batch", "decode_seeded_adaptive",
            "decode_seeded_batch_adaptive", "seeded_h_tile",
            "encode_seeded_fused", "decode_replay", "decode_symbol_major",
-           "detect_interpret",
+           "decode_edge_permuted", "detect_interpret",
            "interpret_only"]
 
 SEEDED_MODES = ("dense_tile", "gather")
@@ -537,6 +553,226 @@ def decode_symbol_major(tgt: jax.Array, nbr: jax.Array, w: jax.Array,
         out_shape=jax.ShapeDtypeStruct((N, V), jnp.float32),
         interpret=interpret,
     )(tgt, nbr, w, scale, counts, values)
+
+
+# --------------------------------------------------- edge-permuted decode --
+
+_VREG_BITS = 10          # log2(8 sublanes x 128 lanes): slot bits in a vreg
+
+
+def _stage_test(k: int):
+    """The int32 mask that tests stage ``k``'s bit of a switch word."""
+    return jnp.int32(np.array(1 << (k % 32), np.uint32).view(np.int32))
+
+
+def _in_vreg_partner(bit: int):
+    """For a stage on slot bit ``bit`` (< 10; slot ``row·128 + lane``):
+    the ``(axis, size, stride)`` of its exchange and the ``(8, 128)``
+    mask of the slots whose partner lies below them."""
+    axis, size = (1, 128) if bit < 7 else (0, 8)
+    s = 1 << (bit if bit < 7 else bit - 7)
+    high = (jax.lax.broadcasted_iota(jnp.int32, (8, 128), axis) & s) != 0
+    return axis, size, s, high
+
+
+def _exchange(x, partner, sw):
+    """Where ``sw``, take the content of the slot's partner: two rolls and
+    two selects, on lanes below bit 7 and on sublanes from it."""
+    axis, size, s, high = partner
+    above = pltpu.roll(x, size - s, axis)             # x at index + s
+    below = pltpu.roll(x, s, axis)                    # x at index - s
+    return jnp.where(sw, jnp.where(high, below, above), x)
+
+
+# Vregs whose in-vreg stages one loop step interleaves, and how many
+# consecutive vreg-level stages one pass applies to a group of vregs held
+# in registers.
+_BENES_UNROLL = 4
+_BENES_GROUP = 3
+
+
+def _benes(words_ref, planes, n_vregs: int, *, inverse: bool):
+    """Apply the routed Beneš network (or, with the stages reversed, its
+    inverse) in place to each ``(n_vregs, 8, 128)`` VMEM ref of
+    ``planes``.  Its bits run high-low-high, so the stages on bits 10 and
+    up (a vreg and its partner vreg, selects only) sit at both ends, run
+    ``_BENES_GROUP`` at a time on groups of vregs in registers, and the
+    19 on bits 0-9 form one block applied to each vreg in registers,
+    ``_BENES_UNROLL`` vregs a loop step."""
+    bits = stage_bits(n_vregs.bit_length() - 1 + _VREG_BITS)
+    ks = list(range(len(bits)))[::-1 if inverse else 1]
+    outer = [k for k in ks if bits[k] >= _VREG_BITS]
+    inner = [k for k in ks if bits[k] < _VREG_BITS]
+
+    def switch(word, k):
+        return (word & _stage_test(k)) != 0
+
+    def vreg_stages(run):
+        """Consecutive stages on vreg bits ``lo .. lo + g - 1``: each loop
+        step loads the ``2^g`` vregs that differ only there."""
+        cs = [bits[k] - _VREG_BITS for k in run]
+        lo, g = min(cs), len(run)
+
+        def group(q, c):
+            base = ((q >> lo) << (lo + g)) | (q & ((1 << lo) - 1))
+            idx = [base + (i << lo) for i in range(1 << g)]
+            words = {}
+
+            def word(w, i):
+                if (w, i) not in words:
+                    words[w, i] = words_ref[w * n_vregs + idx[i]]
+                return words[w, i]
+
+            xs = [[ref[i] for i in idx] for ref in planes]
+            for k, cbit in zip(run, cs):
+                d = 1 << (cbit - lo)
+                for i in range(1 << g):
+                    if i & d:
+                        continue
+                    sw = switch(word(k // 32, i), k)
+                    for x in xs:
+                        x[i], x[i | d] = (jnp.where(sw, x[i | d], x[i]),
+                                          jnp.where(sw, x[i], x[i | d]))
+            for ref, x in zip(planes, xs):
+                for i, v in enumerate(idx):
+                    ref[v] = x[i]
+            return c
+
+        jax.lax.fori_loop(0, n_vregs >> g, group, 0)
+
+    unroll = min(_BENES_UNROLL, n_vregs)
+
+    def in_vreg(q, c):
+        vs = [q * unroll + u for u in range(unroll)]
+        words = [{w: words_ref[w * n_vregs + v]
+                  for w in sorted({k // 32 for k in inner})} for v in vs]
+        xs = [[ref[v] for ref in planes] for v in vs]
+        for k in inner:
+            partner = _in_vreg_partner(bits[k])
+            for u in range(len(vs)):
+                sw = switch(words[u][k // 32], k)
+                xs[u] = [_exchange(x, partner, sw) for x in xs[u]]
+        for v, x_v in zip(vs, xs):
+            for ref, x in zip(planes, x_v):
+                ref[v] = x
+        return c
+
+    half = len(outer) // 2
+    for side in (outer[:half], None, outer[half:]):
+        if side is None:
+            jax.lax.fori_loop(0, n_vregs // unroll, in_vreg, 0)
+            continue
+        for i in range(0, len(side), _BENES_GROUP):
+            vreg_stages(side[i:i + _BENES_GROUP])
+
+
+def _edge_permuted_kernel(words_ref, coeff_ref, vals_ref, erased_ref,
+                          out_vals_ref, out_erased_ref, xv, xf, *,
+                          iters: int, geom):
+    """One payload column's fixed-``iters`` decode; the value and erasure
+    carry live in the output refs, the edge planes in ``xv`` / ``xf``."""
+    nN, nP, nE = geom.var_vregs, geom.check_vregs, geom.slot_vregs
+    out_vals_ref[...] = vals_ref[...]
+    out_erased_ref[...] = erased_ref[...]
+    xv[...] = jnp.zeros(xv.shape, jnp.float32)
+    xf[...] = jnp.zeros(xf.shape, jnp.float32)
+    zero = jnp.zeros((nP, 8, 128), jnp.float32)
+
+    def round_(carry):
+        t, _ = carry
+        vals, e = out_vals_ref[...], out_erased_ref[...]
+        for s in range(geom.l_max):          # variable-major edges
+            xv[pl.ds(s * nN, nN)] = vals
+            xf[pl.ds(s * nN, nN)] = e
+        _benes(words_ref, (xv, xf), nE, inverse=False)
+        cnt, acc, ecoef = zero, zero, zero   # per check, over its planes
+        for s in range(geom.r_max):
+            sl = pl.ds(s * nP, nP)
+            w, f = coeff_ref[sl], xf[sl] != 0.0
+            edge = w != 0.0
+            cnt = cnt + jnp.where(edge & f, 1.0, 0.0)
+            acc = acc + jnp.where(edge & ~f, w * xv[sl], 0.0)
+            ecoef = ecoef + jnp.where(edge & f, w, 0.0)
+        new = -acc / jnp.where(ecoef == 0.0, 1.0, ecoef)
+        solvable = cnt == 1.0
+        for s in range(geom.r_max):          # propose on the erased edge
+            sl = pl.ds(s * nP, nP)
+            xf[sl] = jnp.where(solvable & (coeff_ref[sl] != 0.0)
+                               & (xf[sl] != 0.0), 1.0, 0.0)
+            xv[sl] = new
+        if nE > geom.r_max * nP:
+            tail = nE - geom.r_max * nP
+            xf[pl.ds(geom.r_max * nP, tail)] = jnp.zeros((tail, 8, 128),
+                                                         jnp.float32)
+        _benes(words_ref, (xv, xf), nE, inverse=True)
+        resolved = jnp.zeros(vals.shape, jnp.bool_)
+        for s in range(geom.l_max):          # lowest resolving check wins
+            sl = pl.ds(s * nN, nN)
+            f = xf[sl] != 0.0
+            vals = jnp.where(f & ~resolved, xv[sl], vals)
+            resolved = resolved | f
+        e = jnp.where(resolved, 0.0, e)
+        out_vals_ref[...] = vals
+        out_erased_ref[...] = e
+        return t + 1, _progress(resolved, e)
+
+    # A round that resolves nothing leaves every later round nothing to
+    # do, so stopping there gives the fixed-D result exactly.
+    jax.lax.while_loop(lambda c: (c[0] < iters) & c[1], round_,
+                       (jnp.int32(0), jnp.max(erased_ref[...]) > 0.0))
+
+
+def _progress(resolved, e):
+    """Whether another round can resolve anything: this one resolved some
+    coordinate, and some coordinate is still erased."""
+    return ((jnp.max(jnp.where(resolved, 1.0, 0.0)) > 0.0)
+            & (jnp.max(e) > 0.0))
+
+
+@functools.partial(jax.jit, static_argnames=("iters", "geom", "interpret"))
+def decode_edge_permuted(words: jax.Array, coeff: jax.Array,
+                         values: jax.Array, erased_f: jax.Array, *,
+                         iters: int, geom, interpret: bool | None = None):
+    """Fixed-``iters`` flooding decode on the code's edges, no H read.
+
+    ``geom`` is the :class:`repro.core.benes.EdgeGeometry` of the edge
+    network; every operand is whole ``(8, 128)`` vregs, slot-major:
+
+    * ``words (n_words·slot_vregs, 8, 128) i32`` — the routed switch
+      words of the variable-major → check-major edge permutation;
+    * ``coeff (r_max·check_vregs, 8, 128) f32`` — the check-major edge
+      weights, 0 where a slot is no edge;
+    * ``values (V·var_vregs, 8, 128) f32`` — V payload columns, each the
+      code axis padded to ``var_vregs`` vregs (the grid walks them);
+    * ``erased_f (var_vregs, 8, 128) f32`` — 1.0 where erased.
+
+    Each round broadcasts values and flags onto the variable-major edges,
+    permutes both to check-major, counts each check's erased edges and
+    sums its known weighted values over its ``r_max`` planes, proposes
+    ``-sum / coeff`` on the one erased edge of each check with a count of
+    1, permutes back, and gives each erased variable its lowest resolving
+    check's proposal: :func:`repro.core.decoder.peel_round`'s trajectory,
+    the lowest-check tie-break of the other fused kernels.  Returns
+    ``(values, erased_f)`` after ``iters`` rounds; unresolved coordinates
+    keep their input values.  Rounds stop early once one resolves
+    nothing or nothing is erased: the rounds left would change nothing.
+    """
+    interpret = detect_interpret(interpret)
+    nN = geom.var_vregs
+    col = pl.BlockSpec((nN, 8, 128), lambda c: (c, 0, 0))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda c: (0, 0, 0))  # noqa: E731
+    return _pallas_call(
+        "decode_edge_permuted",
+        functools.partial(_edge_permuted_kernel, iters=iters, geom=geom),
+        grid=(values.shape[0] // nN,),
+        in_specs=[whole(words), whole(coeff), col, whole(erased_f)],
+        out_specs=[col, whole(erased_f)],
+        out_shape=[jax.ShapeDtypeStruct(values.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(erased_f.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((geom.slot_vregs, 8, 128), jnp.float32)
+                        for _ in range(2)],
+        interpret=interpret,
+    )(words, coeff, values, erased_f)
 
 
 # --------------------------------------------------- batched fused decode --

@@ -12,6 +12,12 @@
   the ``(p, N)`` H), then :func:`decode_symbol_major` makes one pass over
   lane tiles of the payload.  ``peel_decode(..., backend="pallas")``
   calls it where ``core/decoder.decode_layout`` says "symbol_major".
+* :func:`peel_decode_edge_permuted_pallas` — the fixed-``D`` decode of a
+  narrow payload on the code's edge network (``LDPCCode.edge_network``):
+  no H operand; the payload is padded to whole vregs of the code axis, one
+  grid step per payload column.  ``peel_decode(..., backend=
+  "pallas_tiled")`` calls it on TPU where ``core/decoder.decode_layout``
+  says "edge_permuted".
 * :func:`peel_decode_batch_pallas` — ``B`` independent erasure patterns in
   one launch (grid over the batch, H resident and shared); the kernel side
   of ``CodedComputeEngine.decode_batch``.
@@ -69,6 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.benes import VREG_SLOTS
 from repro.core.padding import pad_axis_to
 from repro.kernels.ldpc_peel.kernel import (
     check_pass,
@@ -85,6 +92,7 @@ from repro.kernels.ldpc_peel.kernel import (
     decode_seeded_adaptive,
     decode_seeded_batch,
     decode_seeded_batch_adaptive,
+    decode_edge_permuted,
     decode_symbol_major,
     detect_interpret,
     encode_seeded_fused,
@@ -100,7 +108,8 @@ __all__ = ["peel_round_pallas", "peel_decode_pallas",
            "peel_decode_adaptive_seeded_pallas",
            "peel_decode_batch_adaptive_seeded_pallas",
            "encode_seeded_fused_pallas", "peel_decode_replay_pallas",
-           "peel_decode_symbol_major_pallas", "peel_trajectory"]
+           "peel_decode_symbol_major_pallas", "peel_trajectory",
+           "peel_decode_edge_permuted_pallas"]
 
 
 @partial(jax.jit, static_argnames=("interpret", "bp", "bv"))
@@ -294,6 +303,39 @@ def peel_decode_symbol_major_pallas(H, values, erased, iters: int, *,
     return _peel_decode_symbol_major_impl(
         H, values, erased, iters=int(iters), slots=max(int(max_degree) - 1, 1),
         bv=int(bv), chunk=128 * math.gcd(int(bv) // 128, 4),
+        interpret=detect_interpret(interpret))
+
+
+@partial(jax.jit, static_argnames=("iters", "geom", "interpret"))
+def _peel_decode_edge_permuted_impl(words, coeff, values, erased, *,
+                                    iters: int, geom, interpret: bool):
+    squeeze = values.ndim == 1
+    vals = values[:, None] if squeeze else values
+    N, V = vals.shape
+    Np = geom.var_vregs * VREG_SLOTS
+    vt = pad_axis_to(vals.T.astype(jnp.float32), Np, 1)
+    ep = pad_axis_to(erased.astype(jnp.float32), Np, 0)
+    out_v, out_e = decode_edge_permuted(
+        words, coeff, vt.reshape(-1, 8, 128), ep.reshape(-1, 8, 128),
+        iters=iters, geom=geom, interpret=interpret)
+    out_v = out_v.reshape(V, Np)[:, :N].T.astype(vals.dtype)
+    return (out_v[:, 0] if squeeze else out_v), out_e.reshape(Np)[:N] > 0.0
+
+
+def peel_decode_edge_permuted_pallas(network, values, erased, iters: int, *,
+                                     interpret: bool | None = None):
+    """Fixed-D decode on the code's edge network, with no H operand.
+
+    ``network`` is the code's :class:`repro.core.benes.EdgeNetwork`
+    (``LDPCCode.edge_network``); values (N,) or (N, V); erased (N,)
+    bool.  Same trajectory and unresolved mask as
+    :func:`peel_decode_tiled_pallas`, values to f32 summation order;
+    unresolved coordinates keep their input values.  The network's tables
+    enter as constants of the caller's program (a few hundred KiB)."""
+    return _peel_decode_edge_permuted_impl(
+        jnp.asarray(network.words.view(np.int32).reshape(-1, 8, 128)),
+        jnp.asarray(network.coeff.reshape(-1, 8, 128)), values, erased,
+        iters=int(iters), geom=network.geometry,
         interpret=detect_interpret(interpret))
 
 

@@ -174,6 +174,113 @@ def test_symbol_major_launch_is_scoped_and_keeps_its_name(one_chip):
     assert "/kernel/decode_symbol_major/pallas_call" in call
 
 
+@pytest.fixture(scope="module")
+def lsq_code():
+    """A (3,6)-regular code of the lsq cells' size, parity-only."""
+    from repro.core.ldpc import make_parity_only_ldpc
+
+    return make_parity_only_ldpc(P, l=3, r=6, seed=0)
+
+
+def test_edge_permuted_fixed(one_chip, lsq_code):
+    """The edge-permuted decode at the lsq cells' size (N = 16384, V = 1),
+    launched inside the master program's ``decode`` scope: the op keeps
+    the kernel's name and the profile reads it under
+    ``decode/kernel/decode_edge_permuted``."""
+    net = lsq_code.edge_network
+    assert net.geometry.slots == 65536 and net.words.shape == (1, 65536)
+    S = lambda *a: _spec(one_chip, *a)
+
+    @jax.jit
+    def master(words, coeff, v, e):
+        with jax.named_scope("decode"):
+            return ops._peel_decode_edge_permuted_impl(
+                words, coeff, v, e, iters=10, geom=net.geometry,
+                interpret=False)
+
+    compiled = _compile(master, S((64, 8, 128), jnp.int32),
+                        S((48, 8, 128)), S((N,)), S((N,), jnp.bool_))
+    call = next(line for line in compiled.as_text().splitlines()
+                if "tpu_custom_call" in line)
+    assert call.lstrip().startswith("%decode_edge_permuted.")
+    scopes = [p for p in call.split('op_name="')[1].split('"')[0].split("/")
+              if not p.startswith("jit(")]
+    assert "/".join(scopes[:3]) == "decode/kernel/decode_edge_permuted"
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_auto_takes_the_edges_for_the_lsq_code_on_tpu(lsq_code, monkeypatch):
+    """On TPU the lsq-size code's fixed single-pattern decode of a narrow
+    payload runs on its edge network (counted once per trace), and its
+    sync driver places no H; the (40, 20) code stays resident or
+    symbol-major, and the batched-adaptive entry stays streamed."""
+    from repro.core import decoder
+    from repro.core.engine import CodedComputeEngine
+    from repro.core.ldpc import make_regular_ldpc
+    from repro.distributed.master import master_decode_operand
+    from repro.obs import metrics
+
+    monkeypatch.setattr(decoder.jax, "default_backend", lambda: "tpu")
+    layout = decoder.decode_layout
+    assert decoder.resolve_backend("auto", lsq_code) == "pallas_tiled"
+    assert layout("pallas_tiled", 1, lsq_code) == "edge_permuted"
+    assert layout("pallas_tiled", decoder._EDGE_PERMUTED_MAX_V,
+                  lsq_code) == "edge_permuted"
+    assert layout("pallas_tiled", decoder._EDGE_PERMUTED_MAX_V + 1,
+                  lsq_code) == "lane_major"
+    gradagg = make_regular_ldpc(20, l=3, r=6, seed=0)
+    assert decoder.resolve_backend("auto", gradagg) == "pallas"
+    assert layout("pallas", 1, gradagg) == "lane_major"
+    assert layout("pallas", 4096, gradagg) == "symbol_major"
+
+    v, e = jax.ShapeDtypeStruct((N,), jnp.float32), jax.ShapeDtypeStruct(
+        (N,), jnp.bool_)
+    with metrics.recording() as reg:
+        jax.eval_shape(lambda v, e: decoder.peel_decode(
+            lsq_code, v, e, 10).values, v, e)
+    counts = {x["labels"]["layout"]: int(x["value"])
+              for x in reg.snapshot().values()
+              if x["name"] == "decoder.layout_total"}
+    assert counts == {"edge_permuted": 1}
+    jaxpr = str(jax.make_jaxpr(lambda v, e, b: decoder.peel_decode_batch_adaptive(
+        lsq_code, v, e, 10, budgets=b).values)(
+            jnp.zeros((1, N)), jnp.zeros((1, N), bool),
+            jnp.zeros((1,), jnp.int32)))
+    assert "decode_fused_batch_adaptive_tiled" in jaxpr
+    assert "decode_edge_permuted" not in jaxpr
+
+    eng = CodedComputeEngine(lsq_code)
+    assert master_decode_operand(eng, None, fixed_width=1) is None
+
+
+def test_master_decode_operand_places_h_where_a_program_reads_it(
+        monkeypatch):
+    """The sync driver's fixed-D decode on the edges reads no H; a driver
+    with another decode (the telemetry budget's and the pipelined folds'
+    batched one), the adaptive engine and a payload past the crossover
+    do."""
+    from repro.core import decoder
+    from repro.core.engine import CodedComputeEngine
+    from repro.core.ldpc import make_regular_ldpc
+    from repro.distributed.master import master_decode_operand
+
+    monkeypatch.setattr(decoder.jax, "default_backend", lambda: "tpu")
+    code = make_regular_ldpc(128, l=3, r=6, seed=0)
+    budget = 300_000                  # too small for the resident kernel
+    eng = CodedComputeEngine(code, vmem_budget_bytes=budget)
+    assert decoder.resolve_backend("auto", code,
+                                   vmem_budget_bytes=budget) == "pallas_tiled"
+    dev = jax.devices("cpu")[0]
+    assert master_decode_operand(eng, dev, fixed_width=1) is None
+    wide = decoder._EDGE_PERMUTED_MAX_V + 1
+    for H in (master_decode_operand(eng, dev),
+              master_decode_operand(eng, dev, fixed_width=wide),
+              master_decode_operand(CodedComputeEngine(
+                  code, vmem_budget_bytes=budget, adaptive=True), dev,
+                  fixed_width=1)):
+        assert H.shape == (code.p, code.N)
+
+
 # Kernels whose in-kernel gathers Mosaic rejects: a compiled launch must
 # fail up front with a clear error, never deep in the compiler (or fall
 # back to another backend unannounced).  No topology needed.
